@@ -41,27 +41,12 @@ WORKER = """
 import json, time
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+from repro.analysis.jaxpr import iter_eqns
 from repro.comm import plan_collective, apply_plan
 
 
-def _sub_jaxprs(v):
-    import jax.core as jc
-    if isinstance(v, jc.ClosedJaxpr):
-        yield v.jaxpr
-    elif isinstance(v, jc.Jaxpr):
-        yield v
-    elif isinstance(v, (list, tuple)):
-        for x in v:
-            yield from _sub_jaxprs(x)
-
-
 def eqn_count(jaxpr):
-    total = len(jaxpr.eqns)
-    for eq in jaxpr.eqns:
-        for v in eq.params.values():
-            for sub in _sub_jaxprs(v):
-                total += eqn_count(sub)
-    return total
+    return sum(1 for _ in iter_eqns(jaxpr))
 
 
 def hlo_count(text):

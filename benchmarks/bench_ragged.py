@@ -49,7 +49,6 @@ MEASURE_RAGGED = """
 import time, json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import pallgatherv, palltoallv
 
 def measure(op, n, sizes, elems, reps=5):
@@ -64,8 +63,8 @@ def measure(op, n, sizes, elems, reps=5):
         fn = lambda v: palltoallv(v, "x", sizes=[list(r) for r in m])
     x = jnp.asarray(rng.randn(n * rows, elems).astype(np.float32))
     out_spec = P() if op == "allgatherv" else P("x")
-    f = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("x"),
-                          out_specs=out_spec, check_rep=False))
+    f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("x"),
+                          out_specs=out_spec, check_vma=False))
     jax.block_until_ready(f(x))  # compile
     ts = []
     for _ in range(reps):

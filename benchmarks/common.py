@@ -24,6 +24,8 @@ class WorkerTimeoutError(RuntimeError):
 
 
 def run_worker(code: str, devices: int, timeout: int = 560, retries: int = 0) -> dict:
+    """Run ``code`` with ``devices`` simulated host devices, pinned to the
+    CPU backend so the worker never contends for an accelerator."""
     pre = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={devices}'\n"
@@ -37,6 +39,7 @@ def run_worker(code: str, devices: int, timeout: int = 560, retries: int = 0) ->
                 capture_output=True,
                 text=True,
                 timeout=timeout,
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
         except subprocess.TimeoutExpired as e:
             # a hung worker gets one more honest shot (transient host load);

@@ -1,3 +1,2 @@
 """repro — optimized-broadcast reproduction grown into a jax serving/training
-system. Importing any subpackage activates the jax API compatibility gate."""
-from . import _jax_compat  # noqa: F401  (side effects: newer-jax names on 0.4.x)
+system."""

@@ -120,21 +120,11 @@ def analyze_compiled(
 ) -> RooflineReport:
     txt = compiled.as_text()
     mod = parse_hlo(txt)
-    cost = {}
-    try:
-        cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jax wraps the dict
-            cost = cost[0] if cost else {}
-    except Exception:
-        pass
-    mem_bytes = 0.0
-    try:
-        ma = compiled.memory_analysis()
-        mem_bytes = float(
-            ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
-        )
-    except Exception:
-        pass
+    cost = compiled.cost_analysis() or {}
+    ma = compiled.memory_analysis()
+    mem_bytes = float(
+        ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
+    )
     wire = mod.collective_wire_bytes()
     return RooflineReport(
         arch=arch,

@@ -136,7 +136,7 @@ def ring_allreduce(x: jax.Array, axis_name, *, unroll: int = 1) -> jax.Array:
         acc = received + lax.dynamic_slice(b, (recv_idx, 0), (1, chunk))[0]
         return b, acc
 
-    acc0 = lax.pvary(jnp.zeros((chunk,), dtype), axis_name)
+    acc0 = lax.pcast(jnp.zeros((chunk,), dtype), axis_name, to="varying")
     _, acc = lax.fori_loop(0, n - 1, rs_body, (buf, acc0), unroll=unroll)
     owned = (rank + 1) % n
     buf = lax.dynamic_update_slice(buf, acc[None], (owned, 0))

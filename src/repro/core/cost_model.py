@@ -20,6 +20,7 @@ from typing import Sequence
 __all__ = [
     "Hardware",
     "TPU_V5E",
+    "hardware_for",
     "CPU_SIM",
     "calibrate_t_launch",
     "t_exec_path",
@@ -75,6 +76,23 @@ TPU_V5E = Hardware(
     hbm_bw=819e9,
     t_launch=8e-6,
 )
+
+# jax ``Device.device_kind`` -> fabric constants. A device that is not here
+# has no constants yet; pricing it as another chip would be a silent lie.
+_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The :class:`Hardware` of a device as jax names it
+    (``jax.devices()[0].device_kind``). Raises for an unknown kind."""
+    try:
+        return _BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no Hardware constants for device_kind {device_kind!r}; "
+            f"known kinds: {sorted(_BY_DEVICE_KIND)}"
+        ) from None
+
 
 # Constants for interpreting CPU microbenchmarks (used only to sanity-check
 # measured-vs-model shape agreement in benchmarks; absolute values are

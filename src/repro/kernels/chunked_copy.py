@@ -23,6 +23,8 @@ import functools
 import jax
 from jax.experimental import pallas as pl
 
+from .interpret import resolve_interpret
+
 __all__ = ["chunked_copy"]
 
 # 8 * 128 lanes * 4 sublanes: a full VREG-aligned tile row count
@@ -42,8 +44,7 @@ def chunked_copy(x: jax.Array, *, chunk_elems: int = 64 * 1024, interpret: bool 
     grid's implicit bounds mask (no pad copy is ever materialized).
     """
     assert x.ndim == 1, "chunked_copy operates on flat comm buffers"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n = x.size
     chunk_elems = max(_LANE, min(chunk_elems, max(n, _LANE)))
     num_chunks = pl.cdiv(n, chunk_elems)

@@ -10,8 +10,8 @@ HBM every round. This kernel does the merge in ONE VMEM pass — read the
 current rows and the received rows, add-or-select-or-keep under the per-row
 mode, write back — with the current block aliased to the output
 (``input_output_aliases``) so no extra block is materialized. Same
-grid-over-chunks contract as :func:`repro.kernels.chunked_copy`: the Mosaic
-pipeliner double-buffers row (k+1)'s HBM read under row k's write.
+grid-over-tiles contract as :func:`repro.kernels.chunked_copy`: the Mosaic
+pipeliner double-buffers tile (k+1)'s HBM read under tile k's write.
 
 The per-row mode (0 = keep, 1 = overwrite, 2 = accumulate) is data, not
 kernel structure, so one kernel serves combining AND overwriting rounds —
@@ -28,18 +28,25 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .interpret import resolve_interpret
+
 __all__ = ["fused_combine", "fused_combine_update"]
 
-# column tile: VREG-lane aligned, small enough that three (1, _COL_BLOCK)
-# buffers triple-buffer comfortably in VMEM at any dtype
-_COL_BLOCK = 2048
+# Tiles follow the TPU block rule: each of the last two block dimensions is
+# either the whole array dimension or a multiple of the (8, 128) tile (row
+# blocks are kept to multiples of 32, which also covers bf16 and int8). A
+# tile holds about _TILE_BYTES, so the four double-buffered operands stay
+# far below the default scoped VMEM limit at any row count and dtype.
+_ROW_BLOCK = 256
+_TILE_BYTES = 512 << 10
+_LANE = 128
 
 # row modes
 KEEP, OVERWRITE, ACCUMULATE = 0, 1, 2
 
 
 def _merge_kernel(cur_ref, recv_ref, m_ref, out_ref):
-    m = m_ref[0, 0]
+    m = m_ref[...]                                    # (rows, 1)
     cur = cur_ref[...]
     rec = recv_ref[...]
     # where(mode, ..., cur) — NOT cur + where(mode, rec, 0): kept rows must
@@ -57,22 +64,17 @@ def fused_combine(cur: jax.Array, recv: jax.Array, row_mode: jax.Array, *,
     of KEEP (0) / OVERWRITE (1) / ACCUMULATE (2). Must be called inside a
     trace (jit/shard_map) like the executors that own it.
     """
-    # function-level import: ops imports this module at load time, so the
-    # shared interpret resolver has to be pulled in lazily here
-    from .ops import resolve_interpret
-
     interpret = resolve_interpret(interpret)
     B, C = cur.shape
-    colb = min(C, _COL_BLOCK)
+    rowb = B if B <= _ROW_BLOCK else _ROW_BLOCK
+    colb = max(_LANE, _TILE_BYTES // (rowb * cur.dtype.itemsize) // _LANE * _LANE)
+    colb = C if C <= colb else colb
+    tile = pl.BlockSpec((rowb, colb), lambda i, j: (i, j))
     return pl.pallas_call(
         _merge_kernel,
-        grid=(B, pl.cdiv(C, colb)),
-        in_specs=[
-            pl.BlockSpec((1, colb), lambda i, j: (i, j)),
-            pl.BlockSpec((1, colb), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, colb), lambda i, j: (i, j)),
+        grid=(pl.cdiv(B, rowb), pl.cdiv(C, colb)),
+        in_specs=[tile, tile, pl.BlockSpec((rowb, 1), lambda i, j: (i, 0))],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, C), cur.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,

@@ -22,13 +22,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific scratch spaces; interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from .interpret import resolve_interpret
 
 __all__ = ["flash_attention"]
 
@@ -109,11 +105,11 @@ def flash_attention(
     prefix: int = 0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, T, H, hd); k, v: (B, S, KV, hd) with H % KV == 0.
 
-    Returns (B, T, H, hd). Set ``interpret=False`` on real TPUs.
+    Returns (B, T, H, hd). ``interpret=None`` follows the backend.
     """
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
@@ -134,9 +130,9 @@ def flash_attention(
         bk=bk,
     )
     scratch = [
-        _VMEM((bq, hd), jnp.float32),
-        _VMEM((bq,), jnp.float32),
-        _VMEM((bq,), jnp.float32),
+        pltpu.VMEM((bq, hd), jnp.float32),
+        pltpu.VMEM((bq,), jnp.float32),
+        pltpu.VMEM((bq,), jnp.float32),
     ]
     return pl.pallas_call(
         kernel,
@@ -149,5 +145,5 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, H, hd), q.dtype),
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -28,10 +28,12 @@ Two paths, one control flow:
   contract: parity suites compare it bit-for-bit against
   ``simulate_lowered`` and the unrolled executor.
 * **TPU** — the same round/class loop issues
-  ``pltpu.make_async_remote_copy`` RDMA per active pair, with a neighbor
-  barrier per class so a sender never overwrites a landing slot its partner
-  has not consumed. Exercised only on real hardware (the repo's CI is CPU);
-  the interpret path above pins the semantics it must reproduce.
+  ``pltpu.make_async_remote_copy`` RDMA per active pair, straight from the
+  sender's HBM buffer into the receiver's VMEM landing slot, with a
+  per-class ready signal so a sender never overwrites a landing slot its
+  partner has not consumed. CI compiles it for a described v5e
+  (``tests/test_tpu_compile.py``); the interpret path above pins the
+  semantics it must reproduce.
 """
 from __future__ import annotations
 
@@ -44,7 +46,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..core.schedules import KernelTables, LoweredSchedule, pack_tables
-from .ops import on_tpu, resolve_interpret
+from .interpret import on_tpu, resolve_interpret
 
 __all__ = ["inkernel_replay", "inkernel_replay_shared"]
 
@@ -172,114 +174,163 @@ def _neighbor_tables(tables: KernelTables):
     return dst_of, src_of
 
 
-def _rdma_kernel(tables: KernelTables, axis_name: str, cols: int, *refs):
+def _rdma_kernel(tables: KernelTables, axis_name: str,
+                 send_t, recv_t, lo_t, hi_t, comb_t, dst_of_t, src_of_t,
+                 talks_t, _buf_ref, out_ref, land, stage, send_sem, recv_sem,
+                 *ready):
+    """One grid step per round. The buffer stays in HBM (``out_ref``, the
+    aliased input) as ``(K, sub, 128)``, so that a chunk row is a slice of
+    the untiled leading axis; VMEM holds one landing slot and one merge
+    stage.
+
+    Flow control is per lane class: a receiver signals its sender's
+    ``ready[c]`` semaphore once its landing slot is free, and the sender
+    waits for that signal before its remote copy. Each class has one fixed
+    partner per rank, so the signals of successive rounds cannot be
+    confused with another partner's. The barrier semaphore is used once, at
+    the first step, so that no rank touches a partner that has not entered
+    the kernel yet."""
     from jax.experimental.pallas import tpu as pltpu
 
-    C = tables.num_classes
-    (send_t, recv_t, lo_t, hi_t, comb_t, dst_of_t, src_of_t,
-     buf_ref, out_ref) = refs[:9]
-    scratch = refs[9:]  # per class: send_scr, recv_scr, send_sem, recv_sem
-
+    logical = pltpu.DeviceIdType.LOGICAL
     s = pl.program_id(0)
     me = lax.axis_index(axis_name)
 
     @pl.when(s == 0)
-    def _init():
-        out_ref[...] = buf_ref[...]
+    def _handshake():
+        barrier = pltpu.get_barrier_semaphore()
+        count = jnp.int32(0)
+        for q in range(tables.n):
+            @pl.when(talks_t[me, q] == 1)
+            def _signal(q=q):
+                pltpu.semaphore_signal(barrier, device_id=q,
+                                       device_id_type=logical)
 
-    barrier = pltpu.get_barrier_semaphore()
-    for c in range(C):
-        block = tables.blocks[c]
-        send_scr, recv_scr, send_sem, recv_sem = scratch[4 * c:4 * c + 4]
+            count = count + talks_t[me, q]
+        pltpu.semaphore_wait(barrier, count)
+
+    for c, (perm, block) in enumerate(zip(tables.perms, tables.blocks)):
+        if block == 0 or not perm:
+            continue
         dst = dst_of_t[c, me]
         src = src_of_t[c, me]
-        is_src = dst != me
-        is_dst = src != me
+        slot = land.at[pl.ds(0, block)]
 
-        # neighbor barrier: both partners must have finished the previous
-        # round's merge before anyone overwrites a landing slot
-        @pl.when(is_src)
-        def _sig_dst():
-            pltpu.semaphore_signal(
-                barrier, device_id=dst,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
+        @pl.when(src != me)
+        def _slot_free():
+            pltpu.semaphore_signal(ready[c], device_id=src,
+                                   device_id_type=logical)
 
-        @pl.when(is_dst)
-        def _sig_src():
-            pltpu.semaphore_signal(
-                barrier, device_id=src,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
-
-        pltpu.semaphore_wait(
-            barrier, is_src.astype(jnp.int32) + is_dst.astype(jnp.int32)
-        )
-
-        @pl.when(is_src)
+        @pl.when(dst != me)
         def _send():
-            # stage the outgoing block, then kernel-initiated RDMA to the
-            # partner's landing scratch — no host round-trip, no relaunch
-            send_scr[...] = out_ref[pl.ds(send_t[c, s, me], block), :]
+            pltpu.semaphore_wait(ready[c], 1)
             rdma = pltpu.make_async_remote_copy(
-                src_ref=send_scr, dst_ref=recv_scr,
-                send_sem=send_sem, recv_sem=recv_sem,
-                device_id=dst, device_id_type=pltpu.DeviceIdType.LOGICAL,
+                src_ref=out_ref.at[pl.ds(send_t[c, s, me], block)],
+                dst_ref=slot, send_sem=send_sem, recv_sem=recv_sem,
+                device_id=dst, device_id_type=logical,
             )
             rdma.start()
             rdma.wait_send()
 
-        @pl.when(is_dst)
-        def _recv():
-            pltpu.semaphore_wait(recv_sem, 1)
-            r0 = recv_t[c, s, me]
-            cur = out_ref[pl.ds(r0, block), :]
-            rec = recv_scr[...]
-            rows = lax.broadcasted_iota(jnp.int32, (block, cols), 0)
+        @pl.when(src != me)
+        def _merge():
+            window = out_ref.at[pl.ds(recv_t[c, s, me], block)]
+            # a DMA semaphore is waited through a descriptor of the copy
+            # that lands here: same landing ref and semaphores
+            pltpu.make_async_remote_copy(
+                src_ref=window, dst_ref=slot, send_sem=send_sem,
+                recv_sem=recv_sem, device_id=src, device_id_type=logical,
+            ).wait_recv()
+            cur_ref = stage.at[pl.ds(0, block)]
+            pltpu.sync_copy(window, cur_ref)
+            rows = lax.broadcasted_iota(jnp.int32, (block, 1, 1), 0)
             mode = ((rows >= lo_t[c, s, me]) & (rows < hi_t[c, s, me])
                     ).astype(jnp.int32) * (1 + comb_t[c, s])
-            out_ref[pl.ds(r0, block), :] = jnp.where(
-                mode == 2, cur + rec, jnp.where(mode == 1, rec, cur)
-            )
+            sub = cur_ref.shape[1]
+            tile = _merge_tile(sub)
+
+            # merge one (block, tile, 128) slab at a time, so that the
+            # values in flight stay small next to the two slots
+            def merge_slab(t, carry):
+                i = pl.multiple_of(t * tile, tile)
+                cur = cur_ref[:, pl.ds(i, tile), :]
+                rec = slot[:, pl.ds(i, tile), :]
+                cur_ref[:, pl.ds(i, tile), :] = jnp.where(
+                    mode == 2, cur + rec, jnp.where(mode == 1, rec, cur)
+                )
+                return carry
+
+            lax.fori_loop(0, sub // tile, merge_slab, 0)
+            pltpu.sync_copy(cur_ref, window)
+
+
+_LANES = 128
+# the buffer's middle axis is padded to a multiple of this many sublanes
+_SUBLANES = 8
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM footprint of a buffer on the (sublane, 128) tiling."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, sub, lanes = shape
+    tile = 8 * (4 // itemsize)
+    return (int(np.prod(lead)) * -(-sub // tile) * tile
+            * -(-lanes // _LANES) * _LANES * itemsize)
+
+
+def _merge_tile(sub: int) -> int:
+    """The largest power-of-two slab height, at most 512, dividing ``sub``."""
+    tile = 512
+    while sub % tile:
+        tile //= 2
+    return tile
 
 
 def _rdma_replay(tables: KernelTables, buf: jax.Array,
                  axis_name: str) -> jax.Array:
     from jax.experimental.pallas import tpu as pltpu
 
-    T = tables.num_rounds
-    _K, cols = buf.shape
+    K, cols = buf.shape
+    wide = -(-cols // (_SUBLANES * _LANES)) * _SUBLANES * _LANES
+    x = buf if wide == cols else jnp.pad(buf, ((0, 0), (0, wide - cols)))
+    x = x.reshape(K, wide // _LANES, _LANES)
     dst_of, src_of = _neighbor_tables(tables)
-    scratch = []
-    for block in tables.blocks:
-        scratch += [
-            pltpu.VMEM((block, cols), buf.dtype),   # send staging
-            pltpu.VMEM((block, cols), buf.dtype),   # landing slot
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ]
-    full = pl.BlockSpec(buf.shape, lambda s: (0,) * buf.ndim)
+    n = tables.n
+    talks = np.zeros((n, n), np.int32)
+    talks[np.arange(n)[None, :], dst_of] = 1
+    talks[np.arange(n)[None, :], src_of] = 1
+    np.fill_diagonal(talks, 0)
+    slot = (max(tables.blocks),) + x.shape[1:]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(T,),
-        in_specs=[full],
-        out_specs=full,
-        scratch_shapes=scratch,
+        num_scalar_prefetch=8,
+        grid=(tables.num_rounds,),
+        in_specs=[hbm],
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM(slot, x.dtype),   # landing slot
+            pltpu.VMEM(slot, x.dtype),   # merge stage
+            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA,
+        ] + [pltpu.SemaphoreType.REGULAR] * tables.num_classes,
     )
-    return pl.pallas_call(
-        functools.partial(_rdma_kernel, tables, axis_name, cols),
+    out = pl.pallas_call(
+        functools.partial(_rdma_kernel, tables, axis_name),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        input_output_aliases={7: 0},
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        input_output_aliases={8: 0},
         compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=0
+            has_side_effects=True, collective_id=0,
+            vmem_limit_bytes=max(16 << 20,
+                                 2 * _vmem_bytes(slot, x.dtype) + (4 << 20)),
         ),
     )(
         jnp.asarray(tables.send_start), jnp.asarray(tables.recv_start),
         jnp.asarray(tables.lo), jnp.asarray(tables.hi),
         jnp.asarray(tables.combine), jnp.asarray(dst_of), jnp.asarray(src_of),
-        buf,
+        jnp.asarray(talks), x,
     )
+    return out.reshape(K, wide)[:, :cols]
 
 
 def inkernel_replay(lowered: LoweredSchedule, buf: jax.Array, axis_name: str,
@@ -291,7 +342,12 @@ def inkernel_replay(lowered: LoweredSchedule, buf: jax.Array, axis_name: str,
     tables = pack_tables(lowered)
     if tables.num_rounds == 0 or tables.num_classes == 0:
         return buf
-    if not interpret and on_tpu():
+    if not interpret:
+        if not on_tpu():
+            raise ValueError(
+                "inkernel_replay(interpret=False) needs a TPU backend; the "
+                f"default backend is {jax.default_backend()!r}"
+            )
         return _rdma_replay(tables, buf, axis_name)
     shared = lax.all_gather(buf, axis_name, axis=0)
     out = inkernel_replay_shared(lowered, shared, interpret=interpret)

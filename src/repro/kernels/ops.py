@@ -7,11 +7,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 from .chunked_copy import chunked_copy as _chunked_copy
 from .combine_update import fused_combine as _fused_combine
 from .flash_attention import flash_attention as _flash
+from .interpret import on_tpu, resolve_interpret
 from .param_update import mix as _mix, scaled_add as _scaled_add
 from .quantize import (
     BLOCK_ELEMS,
@@ -31,21 +30,6 @@ __all__ = [
     "quantize_blocks",
     "dequantize_blocks",
 ]
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Single source of truth for the Pallas ``interpret`` flag.
-
-    ``None`` means "whatever the backend needs": the interpreter off-TPU,
-    real Mosaic lowering on TPU. Every kernel call site must resolve through
-    here — a CPU-backend trace must never embed a literal ``interpret=False``
-    (it would try to Mosaic-lower on a backend that can't).
-    """
-    return (not on_tpu()) if interpret is None else bool(interpret)
 
 
 def chunked_copy(x, *, chunk_elems: int = 64 * 1024, interpret: Optional[bool] = None):

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .interpret import resolve_interpret
+
 __all__ = ["mix", "scaled_add"]
 
 _TILE = 64 * 1024
@@ -64,12 +66,12 @@ def _run(kernel, w, u, a, tile: int, interpret: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def mix(w: jax.Array, u: jax.Array, a, *, tile: int = _TILE, interpret: bool = True) -> jax.Array:
+def mix(w: jax.Array, u: jax.Array, a, *, tile: int = _TILE, interpret: bool | None = None) -> jax.Array:
     """Model averaging: ``(1-a)*w + a*u`` over flat buffers."""
-    return _run(_mix_kernel, w, u, a, tile, interpret)
+    return _run(_mix_kernel, w, u, a, tile, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def scaled_add(w: jax.Array, u: jax.Array, a, *, tile: int = _TILE, interpret: bool = True) -> jax.Array:
+def scaled_add(w: jax.Array, u: jax.Array, a, *, tile: int = _TILE, interpret: bool | None = None) -> jax.Array:
     """SGD-style step: ``w - a*u`` over flat buffers."""
-    return _run(_scaled_add_kernel, w, u, a, tile, interpret)
+    return _run(_scaled_add_kernel, w, u, a, tile, resolve_interpret(interpret))

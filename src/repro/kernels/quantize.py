@@ -6,10 +6,11 @@ A compressed collective hop ships each chunk as a low-precision payload
 the full-precision values: 4x fewer payload bytes at a ~1.6% scale
 overhead. The quantize kernel computes a symmetric abs-max scale per block
 (``scale = max(|x|) / qmax``), divides, clips to the representable range,
-and casts; the dequantize kernel multiplies back. Both run one
-(1, _BLOCK_ELEMS) tile per grid step — the same grid-over-rows contract as
-:func:`repro.kernels.fused_combine`, so the Mosaic pipeliner double-buffers
-block (k+1)'s HBM read under block k's write.
+and casts; the dequantize kernel multiplies back. Both walk the (B, C)
+buffer in its own layout, one (rows, 128 scale blocks) tile per grid step,
+and split each tile into its 256-element blocks inside the kernel, so no
+relayout copy of the buffer is ever made; the Mosaic pipeliner
+double-buffers tile (k+1)'s HBM read under tile k's write.
 
 The clip BEFORE the cast is load-bearing for fp8: ``float8_e4m3fn`` has no
 inf, so an out-of-range cast produces NaN, not saturation. With the abs-max
@@ -50,24 +51,49 @@ QUANT_DTYPES = {
     "fp8": (jnp.float8_e4m3fn, 448.0),
 }
 
+# Tiles follow the TPU block rule (each of the last two block dimensions is
+# the whole array dimension or a multiple of the (8, 128) tile). A column
+# tile of 128 scale blocks puts a full 128-lane row in the scale tile; an
+# (8, 32768) f32 tile is 1 MiB, so the double-buffered operands stay far
+# below the default scoped VMEM limit.
+_ROW_BLOCK = 8
+_COL_BLOCK = 128 * BLOCK_ELEMS
+
 # scale floor for all-zero blocks: keeps scale strictly positive without
 # perturbing the roundtrip (payload is 0 -> dequant 0 * floor == 0)
 _SCALE_FLOOR = 1e-30
 
 
+def _blocked(x):
+    """(rows, cols) -> (rows, cols // BLOCK_ELEMS, BLOCK_ELEMS) scale blocks."""
+    rows, cols = x.shape
+    return x.reshape(rows, cols // BLOCK_ELEMS, BLOCK_ELEMS)
+
+
 def _quantize_kernel(x_ref, v_ref, s_ref, *, qmax, is_int):
-    x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x))
+    x = _blocked(x_ref[...].astype(jnp.float32))
+    amax = jnp.max(jnp.abs(x), axis=-1)
     scale = jnp.maximum(amax, _SCALE_FLOOR) / qmax
-    q = jnp.clip(x / scale, -qmax, qmax)
+    q = jnp.clip(x / scale[:, :, None], -qmax, qmax)
     if is_int:
         q = jnp.round(q)
-    v_ref[...] = q.astype(v_ref.dtype)
-    s_ref[...] = jnp.full_like(s_ref, scale)
+    v_ref[...] = q.reshape(v_ref.shape).astype(v_ref.dtype)
+    s_ref[...] = scale
 
 
 def _dequantize_kernel(v_ref, s_ref, x_ref):
-    x_ref[...] = v_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    v = _blocked(v_ref[...].astype(jnp.float32))
+    x_ref[...] = (v * s_ref[...][:, :, None]).reshape(x_ref.shape)
+
+
+def _tiles(B: int, C: int):
+    """Grid plus the payload and scale block specs over a (B, C) buffer."""
+    rowb = B if B <= _ROW_BLOCK else _ROW_BLOCK
+    colb = C if C <= _COL_BLOCK else _COL_BLOCK
+    grid = (pl.cdiv(B, rowb), pl.cdiv(C, colb))
+    payload = pl.BlockSpec((rowb, colb), lambda i, j: (i, j))
+    scale = pl.BlockSpec((rowb, colb // BLOCK_ELEMS), lambda i, j: (i, j))
+    return grid, payload, scale
 
 
 def quantize_blocks(x: jax.Array, fmt: str, *, interpret: bool) -> tuple[jax.Array, jax.Array]:
@@ -77,22 +103,19 @@ def quantize_blocks(x: jax.Array, fmt: str, *, interpret: bool) -> tuple[jax.Arr
     """
     dtype, qmax = QUANT_DTYPES[fmt]
     B, C = x.shape
-    nblocks = C // BLOCK_ELEMS
+    grid, payload, scale = _tiles(B, C)
 
     def kernel(x_ref, v_ref, s_ref):
         _quantize_kernel(x_ref, v_ref, s_ref, qmax=qmax, is_int=fmt == "int8")
 
     return pl.pallas_call(
         kernel,
-        grid=(B, nblocks),
-        in_specs=[pl.BlockSpec((1, BLOCK_ELEMS), lambda i, j: (i, j))],
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_ELEMS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
+        grid=grid,
+        in_specs=[payload],
+        out_specs=[payload, scale],
         out_shape=[
             jax.ShapeDtypeStruct((B, C), dtype),
-            jax.ShapeDtypeStruct((B, nblocks), jnp.float32),
+            jax.ShapeDtypeStruct((B, C // BLOCK_ELEMS), jnp.float32),
         ],
         interpret=interpret,
     )(x)
@@ -103,16 +126,13 @@ def dequantize_blocks(values: jax.Array, scales: jax.Array, *,
     """Inverse of :func:`quantize_blocks`: (B, C) wire-dtype + per-block f32
     scales back to (B, C) f32."""
     B, C = values.shape
-    nblocks = C // BLOCK_ELEMS
-    assert scales.shape == (B, nblocks), (values.shape, scales.shape)
+    assert scales.shape == (B, C // BLOCK_ELEMS), (values.shape, scales.shape)
+    grid, payload, scale = _tiles(B, C)
     return pl.pallas_call(
         _dequantize_kernel,
-        grid=(B, nblocks),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_ELEMS), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK_ELEMS), lambda i, j: (i, j)),
+        grid=grid,
+        in_specs=[payload, scale],
+        out_specs=payload,
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
         interpret=interpret,
     )(values, scales)

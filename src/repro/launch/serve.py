@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve.engine import Engine
 from repro.train import checkpoint as ck
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     model = Model(cfg)
     if args.ckpt_dir:

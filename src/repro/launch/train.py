@@ -13,6 +13,7 @@ import argparse
 
 from repro.configs import get_config
 from repro.configs.base import RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.train.trainer import Trainer
 
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     run = RunConfig(
         learning_rate=args.lr,

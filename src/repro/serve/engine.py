@@ -98,7 +98,10 @@ class Engine:
         logits, caches = self._prefill(self.params, batch, max_len)
         caches = self._place_caches(caches)
         offset = cfg.prefix_len if cfg.frontend == "vision" else 0
-        cur = logits[:, -1]
+        # the unembedding is padded past the vocabulary; a padded id is no
+        # token, so sampling and log-probabilities see the real ids only
+        vocab = cfg.vocab_size
+        cur = logits[:, -1, :vocab]
         toks, lps = [], []
         key = jax.random.PRNGKey(seed)
         for i in range(steps):
@@ -116,7 +119,7 @@ class Engine:
                 caches,
                 jnp.asarray(T + offset + i, jnp.int32),
             )
-            cur = logits[:, 0]
+            cur = logits[:, 0, :vocab]
         return GenerationResult(
             tokens=np.stack(toks, axis=1), logprobs=np.stack(lps, axis=1), prefill_len=T
         )

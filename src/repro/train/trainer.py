@@ -100,7 +100,7 @@ class Trainer:
 
     def init_state(self, seed: Optional[int] = None):
         seed = self.run.seed if seed is None else seed
-        with jax.set_mesh(self.mesh) if hasattr(jax, "set_mesh") else self.mesh:
+        with jax.set_mesh(self.mesh):
             params = jax.jit(
                 self.model.init,
                 out_shardings=jax.tree.map(lambda s: NamedSharding(self.mesh, s), self._pspecs),

@@ -24,7 +24,9 @@ def run_distributed(code: str, devices: int = 8, timeout: int = 560,
     is set before any jax import, so collectives and sharding see a real
     multi-device platform without accelerators or network access).
 
-    The snippet must print 'PASS' as its last line on success.
+    The snippet must print 'PASS' as its last line on success. The child
+    is pinned to the CPU backend: its devices are simulated on the host, and
+    it must never contend for an accelerator the parent may hold.
     ``env``: extra environment overrides for the subprocess.
     """
     preamble = (
@@ -39,7 +41,7 @@ def run_distributed(code: str, devices: int = 8, timeout: int = 560,
         text=True,
         timeout=timeout,
         cwd=os.path.dirname(SRC),
-        env={**os.environ, **(env or {})},
+        env={**os.environ, "JAX_PLATFORMS": "cpu", **(env or {})},
     )
     if proc.returncode != 0 or "PASS" not in proc.stdout:
         raise AssertionError(

@@ -190,3 +190,11 @@ def test_tuner_calibrate_picks_best():
 
     t.calibrate(fake_measure, sizes=[1 << 16], n=8)
     assert t.select(1 << 16, 8).algo == "chain"
+
+
+def test_hardware_for_device_kind():
+    """Constants are looked up by jax's device_kind; an unknown kind is an
+    error, not another chip's constants."""
+    assert cm.hardware_for("TPU v5 lite") is cm.TPU_V5E
+    with pytest.raises(ValueError, match="no Hardware constants"):
+        cm.hardware_for("cpu")

@@ -9,6 +9,7 @@ try:
 except ImportError:  # offline fallback — see tests/_compat.py
     from _compat import given, settings, strategies as st
 
+from repro.analysis.jaxpr import pallas_eqns
 from repro.kernels import ops, ref
 
 RNG = np.random.RandomState(0)
@@ -153,27 +154,6 @@ def test_chunked_copy_never_materializes_pad():
 # interpret-mode resolution: one helper, every call site
 
 
-def _pallas_eqns(jaxpr):
-    """Yield every pallas_call eqn, recursing through sub-jaxpr params."""
-    import jax.core as jc
-
-    def subs(v):
-        if isinstance(v, jc.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jc.Jaxpr):
-            yield v
-        elif isinstance(v, (list, tuple)):
-            for x in v:
-                yield from subs(x)
-
-    for eq in jaxpr.eqns:
-        if eq.primitive.name == "pallas_call":
-            yield eq
-        for v in eq.params.values():
-            for sub in subs(v):
-                yield from _pallas_eqns(sub)
-
-
 def test_resolve_interpret_tiers():
     """None defers to the backend probe; explicit bools always win."""
     from repro.kernels.ops import on_tpu, resolve_interpret
@@ -212,7 +192,7 @@ def test_cpu_traces_never_embed_compiled_pallas():
     found = 0
     for fn, name in cases:
         jx = jax.make_jaxpr(lambda _=None: fn())()
-        eqns = list(_pallas_eqns(jx.jaxpr))
+        eqns = pallas_eqns(jx.jaxpr)
         assert eqns, f"{name}: no pallas_call found in trace"
         for eq in eqns:
             assert eq.params["interpret"] is not False, (
@@ -237,6 +217,45 @@ def test_inkernel_replay_honors_resolve_interpret():
     low = lower_schedule(build("pipelined_chain", n, root=0, num_chunks=K))
     shared = jnp.zeros((n, K, 8), jnp.float32)
     jx = jax.make_jaxpr(lambda s: inkernel_replay_shared(low, s))(shared)
-    eqns = list(_pallas_eqns(jx.jaxpr))
+    eqns = pallas_eqns(jx.jaxpr)
     assert len(eqns) == 1, "replay must stay a single launch"
     assert eqns[0].params["interpret"] is not False
+
+
+def test_raw_kernel_defaults_follow_the_backend(monkeypatch):
+    """Left at None, a raw kernel's interpret flag follows the backend: told
+    that it runs on a TPU, each kernel traces a compiled pallas_call."""
+    import importlib
+
+    import jax
+
+    # the package re-exports same-named functions; take the modules
+    mod = lambda name: importlib.import_module(f"repro.kernels.{name}")
+    chunked_copy, flash_attention = mod("chunked_copy"), mod("flash_attention")
+    param_update = mod("param_update")
+    monkeypatch.setattr(mod("interpret"), "on_tpu", lambda: True)
+    x = jnp.zeros(1003, jnp.float32)  # shapes no other test traces
+    q = jnp.zeros((1, 32, 2, 16), jnp.float32)
+    kv = jnp.zeros((1, 32, 1, 16), jnp.float32)
+    cases = {
+        "mix": lambda: param_update.mix(x, x, 0.5),
+        "scaled_add": lambda: param_update.scaled_add(x, x, 0.1),
+        "chunked_copy": lambda: chunked_copy.chunked_copy(x, chunk_elems=256),
+        "flash_attention": lambda: flash_attention.flash_attention(
+            q, kv, kv, bq=32, bk=32),
+    }
+    for name, fn in cases.items():
+        eqns = pallas_eqns(jax.make_jaxpr(fn)().jaxpr)
+        assert eqns, name
+        assert all(eq.params["interpret"] is False for eq in eqns), name
+
+
+def test_inkernel_replay_refuses_compiled_path_off_tpu():
+    """interpret=False asks for the RDMA kernel; off the TPU that is an
+    error, never a quiet switch to the emulation."""
+    from repro.core.schedules import build, lower_schedule
+    from repro.kernels.inkernel_collective import inkernel_replay
+
+    low = lower_schedule(build("pipelined_chain", 4, root=0, num_chunks=4))
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        inkernel_replay(low, jnp.zeros((4, 8), jnp.float32), "x", interpret=False)

@@ -13,7 +13,6 @@ def test_pallgatherv_skewed_and_zero_ranks(dist):
         """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import pallgatherv
 
 n = 4
@@ -27,9 +26,9 @@ for sizes in [(3, 1, 0, 2), (1, 1, 1, 1), (5, 0, 0, 7)]:
     for r in range(n):
         loc[r, :sizes[r]] = full[off[r]:off[r + 1]]
     for compiled in (False, True):
-        f = shard_map(
+        f = jax.shard_map(
             lambda v, c=compiled: pallgatherv(v, "x", sizes=sizes, compiled=c),
-            mesh=mesh, in_specs=P("x"), out_specs=P(), check_rep=False)
+            mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)
         out = np.asarray(f(jnp.asarray(loc.reshape(n * smax, E))))
         assert out.shape == (total, E), (out.shape, total)
         assert np.array_equal(out, full), (sizes, compiled)
@@ -48,7 +47,6 @@ def test_palltoallv_compact_all_algos(dist):
         """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import palltoallv
 
 n, E = 4, 2
@@ -73,10 +71,10 @@ for trial in range(3):
             [blocks[(s, r)] for s in range(n)] + [np.zeros((0, E), np.float32)])
     for compiled in (False, True):
         for algo in ("auto", "pairwise_alltoallv", "ring_alltoallv"):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda v, a=algo, c=compiled: palltoallv(
                     v, "x", sizes=m.tolist(), algo=a, compiled=c),
-                mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)
+                mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
             out = np.asarray(f(jnp.asarray(xin.reshape(n * smax, E))))
             out = out.reshape(n, rmax, E)
             assert np.array_equal(out, exp), (trial, algo, compiled)
@@ -93,7 +91,6 @@ def test_palltoallv_padded_round_trip(dist):
         """
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import palltoallv
 
 n, E = 4, 2
@@ -111,9 +108,9 @@ exp = np.zeros((n, n, bmax, E), np.float32)
 for r in range(n):
     for s in range(n):
         exp[r, s, :m[s, r]] = blocks[(s, r)]
-f = shard_map(
+f = jax.shard_map(
     lambda v: palltoallv(v, "x", sizes=m.tolist(), in_padded=True, out_padded=True),
-    mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)
+    mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)
 out = np.asarray(f(jnp.asarray(xin.reshape(n * n, bmax, E)))).reshape(n, n, bmax, E)
 assert np.array_equal(out, exp)
 print("PASS")
@@ -125,14 +122,13 @@ print("PASS")
 def test_moe_alltoallv_matches_einsum_oracle(dist):
     """The explicit expert-parallel transport (moe_dispatch='alltoallv',
     E=6 over 4 ranks -> ragged partition (2,2,1,1), shared experts on)
-    reproduces the single-host einsum path bit-for-bit, aux loss included
+    reproduces the single-host einsum path to a few f32 ulps, aux loss included
     (me/ce are pmean'd, so aux is the global-batch value)."""
     dist(
         """
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs.base import ModelConfig
 from repro.models import moe as moe_lib
 
@@ -147,14 +143,18 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, T, D), jnp.float32)
 y_ref, aux_ref = moe_lib.moe_ffn(p, x, cfg)
 
 mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
-f = shard_map(
+f = jax.shard_map(
     lambda pp, xx: moe_lib.moe_ffn(pp, xx, cfga, axis_name="dp"),
     mesh=mesh, in_specs=(P(), P("dp")), out_specs=(P("dp"), P()),
-    check_rep=False)
+    check_vma=False)
 y, aux = f(p, x)
 err = float(jnp.max(jnp.abs(y - y_ref)))
 aerr = abs(float(aux) - float(aux_ref))
-assert err == 0.0, err
+# the expert outputs are combined in another order than the einsum path's
+# (XLA's CPU backend picks the order), so allow a few f32 ulps at the scale
+# of the output; a routing or dispatch error moves whole values instead
+ulp = float(np.spacing(np.float32(jnp.max(jnp.abs(y_ref)))))
+assert err <= 4 * ulp, (err, ulp)
 assert aerr < 1e-6, (float(aux), float(aux_ref))
 print("PASS")
 """,

@@ -298,28 +298,8 @@ def test_inkernel_single_launch_and_flat_jaxpr():
     import jax
     import jax.numpy as jnp
 
+    from repro.analysis.jaxpr import pallas_eqns
     from repro.kernels.inkernel_collective import inkernel_replay_shared
-
-    def count_pallas(jaxpr):
-        import jax.core as jc
-
-        def subs(v):
-            if isinstance(v, jc.ClosedJaxpr):
-                yield v.jaxpr
-            elif isinstance(v, jc.Jaxpr):
-                yield v
-            elif isinstance(v, (list, tuple)):
-                for x in v:
-                    yield from subs(x)
-
-        total = 0
-        for eq in jaxpr.eqns:
-            if eq.primitive.name == "pallas_call":
-                total += 1
-            for v in eq.params.values():
-                for sub in subs(v):
-                    total += count_pallas(sub)
-        return total
 
     sizes = {}
     for K in (4, 16, 64):
@@ -328,7 +308,7 @@ def test_inkernel_single_launch_and_flat_jaxpr():
         closed = jax.make_jaxpr(
             lambda s, low=low: inkernel_replay_shared(low, s)
         )(shared)
-        assert count_pallas(closed.jaxpr) == 1, K
+        assert len(pallas_eqns(closed.jaxpr)) == 1, K
         sizes[K] = len(closed.jaxpr.eqns)
     assert len(set(sizes.values())) == 1, sizes
 

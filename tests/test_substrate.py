@@ -154,6 +154,27 @@ def test_engine_greedy_generation():
     np.testing.assert_array_equal(res.tokens, res2.tokens)
 
 
+def test_engine_never_samples_padded_vocab_ids():
+    """The unembedding is padded past vocab_size; even when a padded row
+    dominates the logits, greedy decode stays inside the vocabulary and the
+    log-probabilities are normalised over real ids only."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config("xlstm-350m-smoke"), vocab_size=1000)
+    assert cfg.padded_vocab > cfg.vocab_size
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    tok = params["embed"]["tokens"]
+    # a padded row aligned with every real row wins the argmax everywhere
+    params["embed"]["tokens"] = tok.at[cfg.vocab_size].set(tok[: cfg.vocab_size].sum(0) * 4)
+    prompt = jnp.asarray(np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 8)))
+    logits, _ = m.forward(params, {"tokens": prompt})
+    assert int(jnp.argmax(logits[0, -1])) >= cfg.vocab_size  # the trap is live
+    res = Engine(cfg, params).generate({"tokens": prompt}, steps=4)
+    assert (res.tokens < cfg.vocab_size).all(), res.tokens
+    assert np.isfinite(res.logprobs).all() and (res.logprobs <= 0).all()
+
+
 def test_engine_matches_forward():
     """Greedy engine tokens == argmax of the teacher-forced forward pass."""
     cfg = get_config("xlstm-350m-smoke")
@@ -169,3 +190,27 @@ def test_engine_matches_forward():
     for i in range(4):
         want = int(jnp.argmax(logits[0, 7 + i]))
         assert want == int(res.tokens[0, i]), (i, want, res.tokens)
+
+
+# ------------------------------ launch --------------------------------------
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; unset, the cache sits in
+    .jax_cache at the root of the checkout."""
+    import os
+
+    from repro.launch import compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/operator")
+        assert compile_cache.enable_compile_cache() == "/placed/by/operator"
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
