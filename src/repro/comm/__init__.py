@@ -65,7 +65,6 @@ from .plan import (
     decide,
     expected_wire_bytes,
     plan_cache_clear,
-    plan_cache_info,
     plan_cached,
     plan_collective,
     plan_degraded,
@@ -111,7 +110,6 @@ __all__ = [
     "plan_collective",
     "plan_degraded",
     "plan_cached",
-    "plan_cache_info",
     "plan_cache_clear",
     "cache_stats",
     "decide",

@@ -898,22 +898,25 @@ def pallreduce_tree(
     :func:`pallreduce`).
     """
     spec = bucketing.plan_buckets(tree, bucket_bytes)
-    buckets = bucketing.pack_buckets(tree, spec)
+    with jax.named_scope("pack"):
+        buckets = bucketing.pack_buckets(tree, spec)
     inter = tuple(inter_pod_axes)
     out = []
-    for b in buckets:
+    for i, b in enumerate(buckets):
         if not b.size:
             out.append(b)
             continue
-        if stage:
-            from ..kernels.chunked_copy import chunked_copy
+        with jax.named_scope(f"bucket{i}"):  # index in packing order
+            if stage:
+                from ..kernels.chunked_copy import chunked_copy
 
-            b = chunked_copy(b, chunk_elems=stage_chunk)
-        for ax in axes:
-            b = pallreduce(b, ax, algo=algo, tuner=tuner, inter_pod=(ax in inter),
-                           compiled=compiled, wire_format=wire_format)
+                b = chunked_copy(b, chunk_elems=stage_chunk)
+            for ax in axes:
+                b = pallreduce(b, ax, algo=algo, tuner=tuner, inter_pod=(ax in inter),
+                               compiled=compiled, wire_format=wire_format)
         out.append(b)
-    return bucketing.unpack_buckets(out, spec)
+    with jax.named_scope("unpack"):
+        return bucketing.unpack_buckets(out, spec)
 
 
 def hierarchical_allreduce_axes(mesh) -> tuple:

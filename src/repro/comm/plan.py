@@ -23,7 +23,6 @@ __all__ = [
     "plan_collective",
     "plan_degraded",
     "plan_cached",
-    "plan_cache_info",
     "plan_cache_clear",
     "cache_stats",
     "decide",
@@ -514,10 +513,6 @@ def cache_stats() -> dict:
     ``hits``/``misses``/``evictions`` since the last
     :func:`plan_cache_clear`, plus current ``size`` and ``maxsize``."""
     return dict(_PLAN_CACHE_STATS, size=len(_PLAN_CACHE), maxsize=_PLAN_CACHE_MAX)
-
-
-# historical name — same snapshot
-plan_cache_info = cache_stats
 
 
 def plan_cache_clear() -> None:

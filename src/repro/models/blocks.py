@@ -132,16 +132,17 @@ def apply_block(
 
     if kind in ("attn", "moe", "hybrid"):
         attn_cache = cache.get("attn") if cache else None
-        y, ac = attention(
-            p["attn"],
-            h,
-            spec,
-            mode=mode,
-            positions=positions,
-            prefix_len=prefix_len,
-            cache=attn_cache,
-            cur_pos=cur_pos,
-        )
+        with jax.named_scope("mixer/attn"):
+            y, ac = attention(
+                p["attn"],
+                h,
+                spec,
+                mode=mode,
+                positions=positions,
+                prefix_len=prefix_len,
+                cache=attn_cache,
+                cur_pos=cur_pos,
+            )
         if mode == "prefill" and max_len:
             ac = _grow_cache(ac, max_len, spec)
         if ac is not None:
@@ -149,11 +150,12 @@ def apply_block(
             ac = {**ac, "k": ac["k"].astype(kv_dt), "v": ac["v"].astype(kv_dt)}
             new_cache["attn"] = ac
         if kind == "hybrid":
-            if mode in ("train", "prefill"):
-                m, ms = ssm.mamba_seq(p["ssm"], h, cfg, state=None)
-            else:
-                st = (cache["ssm"]["h"], cache["ssm"]["conv"])
-                m, ms = ssm.mamba_step(p["ssm"], h, st, cfg)
+            with jax.named_scope("mixer/mamba"):
+                if mode in ("train", "prefill"):
+                    m, ms = ssm.mamba_seq(p["ssm"], h, cfg, state=None)
+                else:
+                    st = (cache["ssm"]["h"], cache["ssm"]["conv"])
+                    m, ms = ssm.mamba_step(p["ssm"], h, st, cfg)
             if mode in ("prefill", "decode"):
                 new_cache["ssm"] = {"h": ms[0], "conv": ms[1]}
             y = p["mix_a"].astype(x.dtype) * y + p["mix_m"].astype(x.dtype) * m
@@ -161,12 +163,13 @@ def apply_block(
     elif kind in ("mlstm", "slstm"):
         fn_seq = ssm.mlstm_seq if kind == "mlstm" else ssm.slstm_seq
         fn_step = ssm.mlstm_step if kind == "mlstm" else ssm.slstm_step
-        if mode in ("train", "prefill"):
-            y, st = fn_seq(p["ssm"], h, cfg)
-        else:
-            c = cache["ssm"]
-            st_in = (c["C"], c["n"]) if kind == "mlstm" else (c["c"], c["n"], c["h"])
-            y, st = fn_step(p["ssm"], h, st_in, cfg)
+        with jax.named_scope(f"mixer/{kind}"):
+            if mode in ("train", "prefill"):
+                y, st = fn_seq(p["ssm"], h, cfg)
+            else:
+                c = cache["ssm"]
+                st_in = (c["C"], c["n"]) if kind == "mlstm" else (c["c"], c["n"], c["h"])
+                y, st = fn_step(p["ssm"], h, st_in, cfg)
         if mode in ("prefill", "decode"):
             if kind == "mlstm":
                 new_cache["ssm"] = {"C": st[0], "n": st[1]}
@@ -192,13 +195,14 @@ def apply_block(
         y, _ = attention(p["cross"], hx, spec, cross_kv=(ck, cv))
         x = x + y
 
-    if "mlp" in p:
-        x = x + mlp(p["mlp"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg.act)
-    elif "moe" in p:
-        y, a = moe_lib.moe_ffn(p["moe"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg,
-                               axis_name=axis_name)
-        x = x + y
-        aux = aux + a
+    with jax.named_scope("mlp"):
+        if "mlp" in p:
+            x = x + mlp(p["mlp"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg.act)
+        elif "moe" in p:
+            y, a = moe_lib.moe_ffn(p["moe"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg,
+                                   axis_name=axis_name)
+            x = x + y
+            aux = aux + a
 
     return x, (new_cache if new_cache else None), aux
 

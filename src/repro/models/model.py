@@ -43,8 +43,9 @@ class Model:
 
     def loss(self, params, batch, *, remat: bool = False):
         logits, aux = self.forward(params, batch, remat=remat)
-        labels = jnp.minimum(batch["labels"], self.cfg.padded_vocab - 1)
-        nll = cross_entropy_loss(logits, labels, batch.get("loss_mask"))
+        with jax.named_scope("head"):
+            labels = jnp.minimum(batch["labels"], self.cfg.padded_vocab - 1)
+            nll = cross_entropy_loss(logits, labels, batch.get("loss_mask"))
         return nll + aux, {"nll": nll, "aux": aux}
 
     # ---- serving ----------------------------------------------------------

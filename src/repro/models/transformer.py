@@ -231,10 +231,11 @@ def apply_lm(
         cross_inputs=cross_inputs,
         remat=remat,
     )
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    if mode in ("train", "prefill") and prefix_len and cfg.frontend == "vision":
-        x = x[:, prefix_len:]
-    logits = hint(unembed(params["embed"], x), "btv")
+    with jax.named_scope("head"):
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        if mode in ("train", "prefill") and prefix_len and cfg.frontend == "vision":
+            x = x[:, prefix_len:]
+        logits = hint(unembed(params["embed"], x), "btv")
     return logits, new_caches, aux
 
 

@@ -68,7 +68,10 @@ def _microbatch(batch, k: int):
 
 def _grad_fn(model, run_cfg: RunConfig, grad_specs=None):
     def loss_fn(params, mb):
-        return model.loss(params, mb, remat=run_cfg.remat)
+        # the backward pass and its recompute come out of this scope as
+        # transpose(jvp(fwd)) and .../rematted_computation
+        with jax.named_scope("fwd"):
+            return model.loss(params, mb, remat=run_cfg.remat)
 
     vg = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -100,6 +103,16 @@ def _grad_fn(model, run_cfg: RunConfig, grad_specs=None):
     return compute
 
 
+def _apply_update(optimizer: Optimizer, lr_fn: Callable, grads, opt_state, params):
+    """Clip, learning rate and optimizer update of every step maker, under
+    one ``optimizer`` scope. Returns (params, opt_state, grad_norm, lr)."""
+    with jax.named_scope("optimizer"):
+        grads, gnorm = clip_by_global_norm(grads, 1.0)
+        lr = lr_fn(opt_state["step"])
+        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+    return params, opt_state, gnorm, lr
+
+
 def make_train_step(model, run_cfg: RunConfig, optimizer: Optimizer, lr_fn: Callable, grad_specs=None):
     """pjit path: sharding comes from in/out shardings; collectives are
     GSPMD-inserted (the baseline the paper's mode is compared against).
@@ -109,9 +122,7 @@ def make_train_step(model, run_cfg: RunConfig, optimizer: Optimizer, lr_fn: Call
 
     def train_step(params, opt_state, batch):
         loss, metrics, grads = compute(params, batch)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        lr = lr_fn(opt_state["step"])
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update(metrics)
         return params, opt_state, out
@@ -141,9 +152,7 @@ def make_bcast_train_step(
     for a in dp:
         n_dp *= axis_sizes[a]
 
-    def local_step(params, opt_state, batch):
-        # per-rank grads on the local shard of the batch
-        loss, metrics, grads = compute(params, batch)
+    def bcast_sync(grads):
         if run_cfg.bcast_algo == "ring_allreduce":
             # paper Sec. VII future work: the explicit bandwidth-optimal
             # ring allreduce from the same ppermute substrate
@@ -165,10 +174,15 @@ def make_bcast_train_step(
                     bucket_bytes=run_cfg.bcast_bucket_bytes,
                     inter_pod=(ax == "pod"),
                 )
+        return grads
+
+    def local_step(params, opt_state, batch):
+        # per-rank grads on the local shard of the batch
+        loss, metrics, grads = compute(params, batch)
+        with jax.named_scope("grad_sync"):
+            grads = bcast_sync(grads)
         # deterministic, identical update on every rank
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        lr = lr_fn(opt_state["step"])
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         loss = jax.lax.pmean(loss, dp)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: jax.lax.pmean(v, dp) for k, v in metrics.items()})
@@ -439,25 +453,25 @@ def make_compressed_allreduce_train_step(
             if fmt.compressed
             else grads
         )
-        synced = pallreduce_tree(
-            comp,
-            axes,
-            algo=run_cfg.allreduce_algo,
-            tuner=tuner,
-            bucket_bytes=run_cfg.bcast_bucket_bytes,
-            inter_pod_axes=inter_pod_axes,
-            compiled=run_cfg.compiled_collectives,
-            wire_format=fmt.value,
-        )
+        with jax.named_scope("grad_sync"):
+            synced = pallreduce_tree(
+                comp,
+                axes,
+                algo=run_cfg.allreduce_algo,
+                tuner=tuner,
+                bucket_bytes=run_cfg.bcast_bucket_bytes,
+                inter_pod_axes=inter_pod_axes,
+                compiled=run_cfg.compiled_collectives,
+                wire_format=fmt.value,
+            )
         new_ef = (
             CompressionState.update(comp, fmt.value)
             if fmt.compressed
             else opt_state["ef"]
         )
-        grads = jax.tree.map(lambda g: g / n_dp, synced)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        lr = lr_fn(opt_state["step"])
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree.map(lambda g: g / n_dp, synced)
+        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         opt_state = dict(opt_state, ef=new_ef)
         loss = jax.lax.pmean(loss, dp)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
@@ -524,10 +538,9 @@ def make_degraded_psum_train_step(
                 v = jax.lax.psum(v, ax)
             return v / n_surv
 
-        grads = jax.tree.map(survivor_mean, grads)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        lr = lr_fn(opt_state["step"])
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree.map(survivor_mean, grads)
+        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         loss = survivor_mean(loss)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: survivor_mean(v) for k, v in metrics.items()})
@@ -558,11 +571,10 @@ def _make_comm_sync_step(model, run_cfg, mesh, sync, optimizer, lr_fn, *, mode,
 
     def local_step(params, opt_state, batch):
         loss, metrics, grads = compute(params, batch)
-        grads = sync(grads, axes, inter_pod_axes)
-        grads = jax.tree.map(lambda g: g / n_dp, grads)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        lr = lr_fn(opt_state["step"])
-        params, opt_state = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("grad_sync"):
+            grads = sync(grads, axes, inter_pod_axes)
+            grads = jax.tree.map(lambda g: g / n_dp, grads)
+        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         if post_update is not None:
             params = post_update(params, axes, inter_pod_axes)
         loss = jax.lax.pmean(loss, dp)

@@ -591,9 +591,25 @@ runs = {}
 for mode in ("grad_allreduce", "tuned_allreduce"):
     run = RunConfig(total_steps=4, warmup_steps=1, sync_mode=mode,
                     learning_rate=1e-3, seed=7)
-    params, _, hist = Trainer(cfg, run, mesh=mesh).train(
-        batch=8, seq=32, steps=4, log_every=3)
+    tr = Trainer(cfg, run, mesh=mesh)
+    params, opt, hist = tr.train(batch=8, seq=32, steps=4, log_every=3)
     runs[mode] = (jax.device_get(params), hist)
+
+# the tuned step's gradient sync carries its scope into the compiled HLO
+# (the executable the steps above ran, from the jit cache; the child runs
+# from the root of the checkout, where the benchmark's reader lives)
+from bench import scopes
+from jax.sharding import NamedSharding
+from repro.data.pipeline import batches
+from repro.dist.sharding import batch_specs
+b = next(batches(tr.source, cfg, batch=8, seq=32, start_step=0))
+b = jax.tree.map(lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), b, batch_specs(b, mesh))
+with mesh:
+    names = scopes.op_names(tr._step_fn.lower(params, opt, b).compile().as_text())
+classes = {scopes.classify(n) for n in names.values()}
+assert {"sync", "optimizer", "forward", "backward"} <= classes, classes
+assert any(c.startswith("bucket") for n in names.values() if scopes.classify(n) == "sync"
+           for c in scopes.components(n))
 
 p1, h1 = runs["grad_allreduce"]; p2, h2 = runs["tuned_allreduce"]
 assert abs(h1[0]["loss"] - h2[0]["loss"]) <= 2**-8 * abs(h1[0]["loss"]), (h1[0], h2[0])

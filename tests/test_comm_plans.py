@@ -314,14 +314,14 @@ def test_bench_loader_rejects_bad_rows(tmp_path):
 
 
 def test_plan_cache_hits_and_keying():
-    from repro.comm import plan_cache_clear, plan_cache_info, plan_cached
+    from repro.comm import cache_stats, plan_cache_clear, plan_cached
 
     plan_cache_clear()
     t = Tuner()
     a = plan_cached("allreduce", 1 << 20, 8, tuner=t)
     b = plan_cached("allreduce", 1 << 20, 8, tuner=t)
     assert a is b  # identical point -> the SAME frozen plan object
-    info = plan_cache_info()
+    info = cache_stats()
     assert info["hits"] == 1 and info["misses"] == 1
     # any key component splits the entry
     assert plan_cached("allreduce", 1 << 20, 8, tuner=t, inter_pod=True) is not a
@@ -361,14 +361,14 @@ def test_plan_cache_invalidated_by_tuner_record():
 
 
 def test_plan_cache_bounded():
-    from repro.comm import plan_cache_clear, plan_cache_info, plan_cached
+    from repro.comm import cache_stats, plan_cache_clear, plan_cached
     from repro.comm.plan import _PLAN_CACHE_MAX
 
     plan_cache_clear()
     t = Tuner()
     for i in range(_PLAN_CACHE_MAX + 40):
         plan_cached("bcast", 1024 + i, 4, tuner=t)
-    assert plan_cache_info()["size"] <= _PLAN_CACHE_MAX
+    assert cache_stats()["size"] <= _PLAN_CACHE_MAX
 
 
 def test_decision_fused_path_roundtrip(tmp_path):
