@@ -2,7 +2,7 @@
 activation hints. One source of truth for how tensors land on the mesh."""
 from . import topology
 from .hints import activation_hints, hint
-from .sharding import batch_specs, cache_specs, param_specs
+from .sharding import batch_specs, cache_specs, on_mesh, param_specs
 from .topology import (
     axis_sizes,
     bcast_axes,
@@ -19,6 +19,7 @@ __all__ = [
     "param_specs",
     "batch_specs",
     "cache_specs",
+    "on_mesh",
     "hint",
     "activation_hints",
     "axis_sizes",

@@ -46,6 +46,7 @@ superblock dim.  Functions only read ``mesh.axis_names`` /
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -54,7 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from .topology import DP_AXES, TP_AXIS, axis_sizes
 
-__all__ = ["param_specs", "batch_specs", "cache_specs"]
+__all__ = ["param_specs", "batch_specs", "cache_specs", "on_mesh"]
 
 _ATTN_PROJ = {"wq", "wk", "wv", "wo", "bq", "bk", "bv"}
 
@@ -182,6 +183,22 @@ def param_specs(shapes: Any, mesh, *, fsdp: bool = True,
         return P(*([None] * stacked + ent))
 
     return jax.tree_util.tree_map_with_path(one, shapes)
+
+
+def on_mesh(fn, mesh):
+    """``fn`` traced with ``mesh`` as the ambient abstract mesh, for
+    ``jax.jit``: code inside that splits itself by hand over the mesh (the
+    Mosaic kernels, which XLA cannot partition) sees its axes. ``fn`` itself
+    where the mesh has one device or none."""
+    if mesh is None or mesh.devices.size <= 1:
+        return fn
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
 
 
 def batch_specs(tree: Any, mesh) -> Any:

@@ -5,12 +5,14 @@ Pallas interpreter for correctness) and False on TPU (real Mosaic lowering).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 from .chunked_copy import chunked_copy as _chunked_copy
 from .combine_update import fused_combine as _fused_combine
 from .flash_attention import flash_attention as _flash
 from .interpret import on_tpu, resolve_interpret
+from .mamba_scan import mamba_scan as _mamba_scan
 from .param_update import mix as _mix, scaled_add as _scaled_add
 from .quantize import (
     BLOCK_ELEMS,
@@ -29,6 +31,7 @@ __all__ = [
     "flash_attention",
     "quantize_blocks",
     "dequantize_blocks",
+    "mamba_scan",
 ]
 
 
@@ -105,3 +108,40 @@ def flash_attention(
     return _flash(
         q, k, v, causal=causal, window=window, prefix=prefix, bq=bq, bk=bk, interpret=interpret
     )
+
+
+def mamba_scan(dt, x, Bm, Cm, A, h0, *, chunk: int, interpret: Optional[bool] = None):
+    """Mamba's selective scan: dt, x (Bt, T, di); Bm, Cm (Bt, T, N); A (di, N);
+    h0 (Bt, di, N), all f32. Returns (y (Bt, T, di), h_last (Bt, di, N)) with
+    y_t = sum_n h_t C_t, the skip term left to the caller. Time blocks of
+    about ``chunk`` steps; any T and di.
+
+    XLA cannot partition a Mosaic kernel, so where the ambient mesh
+    (``jax.sharding.get_abstract_mesh``) has axes of more than one device
+    that are not already manual, the scan splits itself with
+    ``jax.shard_map``: rows of the batch over those axes but the model axis,
+    channels over the model axis, where they divide. Rows and channels are
+    independent; the shard_map's transpose sums the cotangents of B, C and A
+    over the axes that split their partners. Code that jits the scan on a
+    multi-device mesh traces it under that mesh (``repro.dist.on_mesh``).
+    """
+    import math
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..dist.topology import TP_AXIS
+
+    scan = functools.partial(_mamba_scan, chunk=chunk, interpret=resolve_interpret(interpret))
+    mesh = jax.sharding.get_abstract_mesh()
+    split = [a for a in mesh.axis_names if mesh.shape[a] > 1 and a not in mesh.manual_axes]
+    if not split:
+        return scan(dt, x, Bm, Cm, A, h0)
+    Bt, _, di = dt.shape
+    rows = tuple(a for a in split if a != TP_AXIS)
+    rows = rows if rows and Bt % math.prod(mesh.shape[a] for a in rows) == 0 else None
+    chans = TP_AXIS if TP_AXIS in split and di % mesh.shape[TP_AXIS] == 0 else None
+    seq, bc, hs = P(rows, None, chans), P(rows, None, None), P(rows, chans, None)
+    return jax.shard_map(scan, in_specs=(seq, seq, bc, bc, P(chans, None), hs),
+                         out_specs=(seq, hs), axis_names=set(split),
+                         check_vma=False)(dt, x, Bm, Cm, A, h0)

@@ -6,6 +6,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 __all__ = [
     "chunked_copy_ref",
@@ -14,6 +15,7 @@ __all__ = [
     "mix_ref",
     "scaled_add_ref",
     "flash_attention_ref",
+    "mamba_scan_ref",
 ]
 
 
@@ -97,3 +99,34 @@ def flash_attention_ref(
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgts,bskh->btkgh", p, v.astype(jnp.float32))
     return out.reshape(B, T, H, hd).astype(q.dtype)
+
+
+def mamba_scan_ref(dt, x, Bm, Cm, A, h0, *, chunk: int):
+    """The selective scan as chunks of ``chunk`` steps: a ``lax.scan`` over
+    the chunks carries the state, and each chunk runs an associative scan
+    over its (Bt, chunk, di, N) f32 state sequence, fused with the C
+    projection, as the model ran it before the kernels. Any T that
+    ``chunk`` divides; any di. Same arguments and results as
+    :func:`repro.kernels.ops.mamba_scan`; the tests' oracle."""
+    Bt, T, di = dt.shape
+    nc = T // chunk
+
+    def rs(a):  # (Bt,T,...) -> (nc,Bt,chunk,...)
+        return jnp.moveaxis(a.reshape(Bt, nc, chunk, *a.shape[2:]), 1, 0)
+
+    def op(u, w):
+        la1, h1 = u
+        la2, h2 = w
+        return (la1 + la2, jnp.exp(la2) * h1 + h2)
+
+    def step(h_in, xs):
+        dt_c, x_c, b_c, c_c = xs             # (Bt,L,di) / (Bt,L,N)
+        log_a = dt_c[..., None] * A          # (Bt,L,di,N)
+        bu = (dt_c * x_c)[..., None] * b_c[..., None, :]
+        la_cum, h_intra = lax.associative_scan(op, (log_a, bu), axis=1)
+        h = h_intra + jnp.exp(la_cum) * h_in[:, None]
+        y_c = jnp.einsum("bldn,bln->bld", h, c_c)
+        return h[:, -1], y_c
+
+    h_last, y_chunks = lax.scan(step, h0, (rs(dt), rs(x), rs(Bm), rs(Cm)))
+    return jnp.moveaxis(y_chunks, 0, 1).reshape(Bt, T, di), h_last

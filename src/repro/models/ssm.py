@@ -1,11 +1,15 @@
 """Recurrent / state-space mixers: mLSTM & sLSTM (xLSTM) and Mamba (S6).
 
 TPU adaptation notes (DESIGN.md Sec. 2): the GPU reference implementations
-use fused CUDA scans; here the sequence dimension is processed *chunkwise* —
-an outer ``lax.scan`` carries the recurrent state across chunks while each
-chunk is computed in parallel (matmuls for mLSTM, ``associative_scan`` for
-the diagonal Mamba recurrence). This keeps the MXU busy and the working set
-in VMEM-sized tiles, which is the TPU-native shape of these operators.
+use fused CUDA scans. Here mLSTM processes the sequence *chunkwise*: an
+outer ``lax.scan`` carries the matrix memory across chunks while each chunk
+is computed in parallel with matmuls, which keeps the MXU busy. Mamba's
+diagonal recurrence has no matmul to give the MXU; it runs as one Pallas
+kernel pair (``kernels/mamba_scan.py``, forward and backward under a custom
+VJP) that walks time in order with the state held in VMEM, so only dt, x,
+B, C, y and the state at each chunk boundary touch HBM. Every shape takes
+it: the kernel wrapper pads di to whole lane tiles and odd prompt lengths
+to whole chunks with steps that leave the state as it was.
 
 Simplification recorded in DESIGN.md: xLSTM's stabilized exponential gating
 is replaced by log-sigmoid gating (decay factors <= 1, unconditionally
@@ -21,13 +25,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from ..kernels import ops
 from .layers import _norm_init, down_proj
 
 __all__ = [
-    "chunked_diag_scan",
     "init_mlstm",
     "mlstm_seq",
     "mlstm_step",
@@ -41,7 +44,7 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# generic chunked diagonal-linear scan: h_t = exp(log_a_t) * h_{t-1} + b_t
+# time chunks
 # ---------------------------------------------------------------------------
 
 
@@ -52,35 +55,6 @@ def _pick_chunk(T: int, chunk: int) -> int:
     while T % L:
         L -= 1
     return L
-
-
-def chunked_diag_scan(log_a, b, h0, chunk: int):
-    """log_a, b: (B, T, *S); h0: (B, *S). Returns (h (B,T,*S), h_last)."""
-    B, T = b.shape[:2]
-    L = _pick_chunk(T, chunk)
-    nc = T // L
-    rest = b.shape[2:]
-    la = log_a.reshape(B, nc, L, *rest)
-    bb = b.reshape(B, nc, L, *rest)
-
-    def op(x, y):
-        la1, h1 = x
-        la2, h2 = y
-        return (la1 + la2, jnp.exp(la2) * h1 + h2)
-
-    # intra-chunk inclusive scan (zero incoming state)
-    la_cum, h_intra = lax.associative_scan(op, (la, bb), axis=2)
-
-    # cross-chunk carry
-    def step(H, xs):
-        la_c, h_c = xs  # (B, L, *S)
-        h = h_c + jnp.exp(la_c) * H[:, None]
-        return h[:, -1], h
-
-    xs = (jnp.moveaxis(la_cum, 1, 0), jnp.moveaxis(h_intra, 1, 0))
-    h_last, h_chunks = lax.scan(step, h0, xs)
-    h = jnp.moveaxis(h_chunks, 0, 1).reshape(B, T, *rest)
-    return h, h_last
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +274,7 @@ def _mamba_conv(p, xb, conv_state=None):
 
 def mamba_seq(p, x, cfg, state=None):
     """Returns (y, (ssm_state (B,di,N), conv_state (B,W-1,di)))."""
-    B, T, d = x.shape
+    B, _, d = x.shape
     di = cfg.ssm_expand * d
     N = cfg.ssm_state
     xz = x @ p["w_in"]
@@ -314,34 +288,8 @@ def mamba_seq(p, x, cfg, state=None):
     A = -jnp.exp(p["a_log"])                             # (di,N)
     h0 = jnp.zeros((B, di, N), jnp.float32) if state is None else state[0]
 
-    # Fused chunkwise scan: the (B, T, di, N) state sequence NEVER
-    # materializes — each chunk's intra-chunk associative scan and the
-    # C-projection happen inside one sequential step (peak state memory is
-    # O(B * chunk * di * N); the unfused version materialized the full T and
-    # pushed hymba train_4k to 27.7 GiB/device — EXPERIMENTS.md §Perf).
-    L = _pick_chunk(T, cfg.ssm_chunk)
-    nc = T // L
-    N = cfg.ssm_state
-
-    def rs(a):  # (B,T,...) -> (nc,B,L,...)
-        return jnp.moveaxis(a.reshape(B, nc, L, *a.shape[2:]), 1, 0)
-
-    def op(u, w):
-        la1, h1 = u
-        la2, h2 = w
-        return (la1 + la2, jnp.exp(la2) * h1 + h2)
-
-    def step(h_in, xs):
-        dt_c, xc_c, b_c, c_c = xs            # (B,L,di) / (B,L,N)
-        log_a = dt_c[..., None] * A          # (B,L,di,N)
-        bu = (dt_c * xc_c)[..., None] * b_c[..., None, :]
-        la_cum, h_intra = lax.associative_scan(op, (log_a, bu), axis=1)
-        h = h_intra + jnp.exp(la_cum) * h_in[:, None]
-        y_c = jnp.einsum("bldn,bln->bld", h, c_c)
-        return h[:, -1], y_c
-
-    h_last, y_chunks = lax.scan(step, h0, (rs(dt), rs(xc), rs(Bm), rs(Cm)))
-    y = jnp.moveaxis(y_chunks, 0, 1).reshape(B, T, di) + p["d_skip"] * xc
+    y, h_last = ops.mamba_scan(dt, xc, Bm, Cm, A, h0, chunk=cfg.ssm_chunk)
+    y = y + p["d_skip"] * xc
     y = down_proj(y.astype(x.dtype) * jax.nn.silu(z), p["w_out"])
     return y, (h_last, conv_state)
 

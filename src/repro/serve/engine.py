@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import comm
 from ..configs.base import ModelConfig
 from ..dist import topology
-from ..dist.sharding import cache_specs, param_specs
+from ..dist.sharding import cache_specs, on_mesh, param_specs
 from ..models import Model
 
 __all__ = [
@@ -72,7 +72,7 @@ class Engine:
                 params = jax.device_put(params, _placements(mesh, pspecs))
         self.params = params
         self._prefill = jax.jit(
-            lambda p, b, ml: self.model.prefill(p, b, max_len=ml),
+            on_mesh(lambda p, b, ml: self.model.prefill(p, b, max_len=ml), mesh),
             static_argnums=(2,),
         )
         self._step = jax.jit(self.model.decode_step)

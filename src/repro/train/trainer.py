@@ -12,7 +12,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig, RunConfig
 from ..data.pipeline import batches, make_source
-from ..dist.sharding import batch_specs, param_specs
+from ..dist.sharding import batch_specs, on_mesh, param_specs
 from ..launch.mesh import dp_axes, make_local_mesh
 from ..models import Model
 from ..optim.optimizers import get_optimizer
@@ -96,7 +96,7 @@ class Trainer:
         else:
             step_fn = make_train_step(self.model, self.run, self.optimizer, self.lr_fn)
             self._pspecs = param_specs(self.model.param_shapes(), mesh)
-        self._step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
+        self._step_fn = jax.jit(on_mesh(step_fn, mesh), donate_argnums=(0, 1))
 
     def init_state(self, seed: Optional[int] = None):
         seed = self.run.seed if seed is None else seed

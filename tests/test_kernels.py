@@ -151,6 +151,146 @@ def test_chunked_copy_never_materializes_pad():
 
 
 # ---------------------------------------------------------------------------
+# Mamba selective scan
+
+
+def _mamba_args(Bt, T, di, N, seed=0):
+    """dt, x, B, C, A, h0 as the model makes them: dt > 0, A < 0, h0 != 0."""
+    rng = np.random.RandomState(seed)
+    f = lambda *s: rng.randn(*s).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (
+        np.log1p(np.exp(f(Bt, T, di) - 1.0)), f(Bt, T, di), f(Bt, T, N), f(Bt, T, N),
+        -np.exp(0.5 * f(di, N)), f(Bt, di, N)))
+
+
+def _mamba_sequential(dt, x, Bm, Cm, A, h0):
+    """One step at a time, in sequence order: the plainest form."""
+    import jax
+
+    def step(h, xs):
+        d, u, b, c = xs
+        h = jnp.exp(d[..., None] * A) * h + (d * u)[..., None] * b[:, None, :]
+        return h, jnp.einsum("bdn,bn->bd", h, c)
+
+    h_last, ys = jax.lax.scan(step, h0, tuple(jnp.moveaxis(a, 1, 0) for a in (dt, x, Bm, Cm)))
+    return jnp.moveaxis(ys, 0, 1), h_last
+
+
+@pytest.mark.parametrize("Bt,T,di,N,chunk", [
+    (2, 200, 1408, 16, 128),  # an odd length: 2 blocks of 128, the last padded; 11 di tiles
+    (2, 36, 96, 16, 16),      # a short prompt: one block of 40; di padded to 128
+    (1, 40, 256, 4, 8),       # one block of the whole sequence; 2 lane columns
+    (2, 48, 384, 8, 16),      # one block of 48 steps; 3 lane columns
+])
+def test_mamba_scan(Bt, T, di, N, chunk):
+    """y, h_last and the gradients with respect to dt, x, B, C, A and h0 of
+    ``ops.mamba_scan`` against the jnp chunked scan and a sequential scan.
+    Every shape takes the kernels; padded steps and channels are sliced off."""
+    import functools
+
+    import jax
+
+    from repro.kernels.mamba_scan import time_blocks
+
+    args = _mamba_args(Bt, T, di, N)
+    rng = np.random.RandomState(1)
+    cts = (jnp.asarray(rng.randn(Bt, T, di), jnp.float32),
+           jnp.asarray(rng.randn(Bt, di, N), jnp.float32))
+    fn = lambda *a: ops.mamba_scan(*a, chunk=chunk)
+    eqns = pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert [eq.params["name"] for eq in eqns] == ["mamba_scan_fwd"]
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(f, args, cts):
+        out, vjp = jax.vjp(f, *args)
+        return out + vjp(cts)
+
+    block = time_blocks(T, chunk)[0]
+    got = run(fn, args, cts)
+    chunked = run(functools.partial(ref.mamba_scan_ref, chunk=block if T % block == 0 else T),
+                  args, cts)
+    sequential = run(_mamba_sequential, args, cts)
+    names = ("y", "h_last", "d_dt", "dx", "dB", "dC", "dA", "dh0")
+    for name, g, c, s in zip(names, got, chunked, sequential):
+        assert g.shape == s.shape, name
+        for want in (c, s):
+            scale = float(jnp.abs(want).max())
+            np.testing.assert_allclose(np.asarray(g), np.asarray(want), rtol=2e-5,
+                                       atol=2e-5 * scale, err_msg=name)
+
+
+def test_mamba_scan_splits_over_the_mesh(dist):
+    """Traced under a multi-device mesh (``dist.on_mesh``, as the trainer
+    and the engine trace), the scan runs as a shard_map: rows over the data
+    axis and channels over the model axis where they divide, replicated
+    where they do not. Values and gradients match a sequential scan."""
+    dist("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+sys.path.insert(0, "tests")
+from test_kernels import _mamba_args, _mamba_sequential
+from repro.dist import on_mesh
+from repro.kernels import ops
+
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+def with_grads(f):
+    def run(args, cts):
+        out, vjp = jax.vjp(f, *args)
+        return out + vjp(cts)
+    return run
+
+for Bt, di in ((2, 256), (1, 96)):
+    args = _mamba_args(Bt, 24, di, 8)
+    rng = np.random.RandomState(1)
+    cts = (jnp.asarray(rng.randn(Bt, 24, di), jnp.float32),
+           jnp.asarray(rng.randn(Bt, di, 8), jnp.float32))
+    want = jax.jit(with_grads(_mamba_sequential))(args, cts)
+    args, cts = jax.device_put((args, cts), NamedSharding(mesh, P()))
+    split = jax.jit(on_mesh(with_grads(lambda *a: ops.mamba_scan(*a, chunk=16)), mesh))
+    assert "shard_map" in str(split.trace(args, cts).jaxpr), (Bt, di)
+    for g, w in zip(split(args, cts), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5,
+                                   atol=2e-5 * float(jnp.abs(w).max()))
+print("PASS")
+""", devices=4)
+
+
+@pytest.mark.parametrize("arch,layers", [("hymba-1.5b", 8), ("xlstm-350m", 0)])
+def test_mamba_scan_in_grad_step(arch, layers):
+    """The grad step of the benchmark's configuration at published widths
+    (traced on abstract shapes, nothing computed): each of hymba's 8 hybrid
+    layers runs the forward kernel in the forward pass and again in the
+    recompute, and the backward kernel once; xLSTM runs none."""
+    import collections
+    import dataclasses
+
+    import jax
+    from repro.configs import get_config
+    from repro.models import Model
+
+    cfg = get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    m = Model(cfg)
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+    jx = jax.make_jaxpr(jax.grad(lambda p, b: m.loss(p, b, remat=True)[0]))(
+        params, {"tokens": tok, "labels": tok})
+    # the layer stack is a scan: a kernel in its body runs once per layer
+    counts, in_scans = collections.Counter(), 0
+    for eq in jx.jaxpr.eqns:
+        if eq.primitive.name == "scan":
+            eqns = pallas_eqns(eq.params["jaxpr"].jaxpr)
+            in_scans += len(eqns)
+            counts.update({k.params["name"]: eq.params["length"] for k in eqns})
+    assert len(pallas_eqns(jx.jaxpr)) == in_scans
+    want = {"mamba_scan_fwd": 2 * layers, "mamba_scan_bwd": layers} if layers else {}
+    assert dict(counts) == want
+
+
+# ---------------------------------------------------------------------------
 # interpret-mode resolution: one helper, every call site
 
 
@@ -188,6 +328,7 @@ def test_cpu_traces_never_embed_compiled_pallas():
          "fused_combine"),
         (lambda: ops.flash_attention(q, kv, kv, causal=True, bq=32, bk=32),
          "flash_attention"),
+        (lambda: ops.mamba_scan(*_mamba_args(1, 16, 128, 4), chunk=8), "mamba_scan"),
     ]
     found = 0
     for fn, name in cases:
@@ -243,6 +384,8 @@ def test_raw_kernel_defaults_follow_the_backend(monkeypatch):
         "chunked_copy": lambda: chunked_copy.chunked_copy(x, chunk_elems=256),
         "flash_attention": lambda: flash_attention.flash_attention(
             q, kv, kv, bq=32, bk=32),
+        "mamba_scan": lambda: mod("mamba_scan").mamba_scan(
+            *_mamba_args(1, 24, 128, 4), chunk=8),
     }
     for name, fn in cases.items():
         eqns = pallas_eqns(jax.make_jaxpr(fn)().jaxpr)

@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import collections
 import os
+import re
 import sys
 
 import jax
@@ -30,7 +31,8 @@ def test_train_step_scopes(arch, mixers):
     tr = Trainer(get_config(arch), RunConfig(total_steps=4, warmup_steps=1, remat=True))
     params, opt = tr.init_state(0)
     batch = {"tokens": jnp.zeros((2, 32), jnp.int32), "labels": jnp.zeros((2, 32), jnp.int32)}
-    names = scopes.op_names(tr._step_fn.lower(params, opt, batch).compile().as_text())
+    text = tr._step_fn.lower(params, opt, batch).compile().as_text()
+    names = scopes.op_names(text)
     paths = [scopes.scope_path(n) for n in names.values()]
     classes = collections.Counter(scopes.classify(p) for p in paths)
     for c in ("forward", "backward", "recompute", "optimizer"):
@@ -41,6 +43,28 @@ def test_train_step_scopes(arch, mixers):
     for c in ("forward", "recompute", "backward"):
         assert {scopes.mixer(p) for p in paths if scopes.classify(p) == c} >= mixers, c
     assert any("head" in scopes.components(p) for p in paths)
+    if "mamba" in mixers:
+        # Mamba's scan kernels under remat: the forward kernel in the forward
+        # pass and the recompute, the backward kernel in the backward pass,
+        # each under mixer/mamba (here the interpreted kernels' ops; on a TPU
+        # the kernel's one custom call). A reduce's combiner runs inside the
+        # reduce, and one in an interpreted branch keeps a name relative to
+        # the branch, so combiners are left out.
+        kernels = {(k, scopes.classify(n), scopes.mixer(n))
+                   for n in scopes.op_names(_without_combiners(text)).values()
+                   for k in ("mamba_scan_fwd", "mamba_scan_bwd") if k in n}
+        assert kernels == {("mamba_scan_fwd", "forward", "mamba"),
+                           ("mamba_scan_fwd", "recompute", "mamba"),
+                           ("mamba_scan_bwd", "backward", "mamba")}, kernels
+
+
+def _without_combiners(hlo_text: str) -> str:
+    """The HLO module's text without the computations that reduces (and
+    reduce-windows) apply: every ``to_apply`` target but a call's."""
+    combiners = {m.group(1) for line in hlo_text.splitlines() if " call(" not in line
+                 for m in re.finditer(r"to_apply=%([\w.\-]+)", line)}
+    blocks = re.split(r"\n(?=\S)", hlo_text)   # a computation starts at column 0
+    return "\n".join(b for b in blocks if b.split(" ", 1)[0].lstrip("%") not in combiners)
 
 
 def test_classify_first_match_wins():

@@ -4,7 +4,9 @@ Nothing runs: the TPU compiler that ships with jax compiles for a chip that
 is described but not attached, and refuses what the chip would refuse
 (misaligned blocks, too much fast memory). Interpret-mode tests cannot see
 either. The widths are the ones the system uses: a 64 MiB f32 gradient
-bucket in 16 chunks, and the serving engine's 4 MiB bf16 weight bucket.
+bucket in 16 chunks, the serving engine's 4 MiB bf16 weight bucket, and
+hymba-1.5b's Mamba scan over a batch of 2 x 2048, alone and inside the
+trainer's step and the engine's prefill on four chips.
 
 This is the only test file that describes a chip. The topology is described
 inside a fixture, never on import: only one process at a time may load the
@@ -79,6 +81,101 @@ def test_quantize_roundtrip_compiles(one_chip, fmt):
         sharding=one_chip)
     _compile(lambda v, s: quantize.dequantize_blocks(v, s, interpret=False),
              values, scales)
+
+
+@pytest.mark.parametrize("Bt,T,di", [
+    (2, 2048, 3200),   # hymba-1.5b's training step: 16 blocks of 128 steps
+    (1, 2047, 1600),   # an odd prompt on half of d_inner: padded steps and lanes
+    (2, 36, 96),       # a short prompt: one block of 40 steps
+])
+def test_mamba_scan_compiles(one_chip, Bt, T, di):
+    """Forward and backward kernels with hymba-1.5b's state of 16 and blocks
+    of up to 128 steps."""
+    from repro.kernels.mamba_scan import mamba_scan
+
+    N = 16
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    args = (f32(Bt, T, di), f32(Bt, T, di), f32(Bt, T, N), f32(Bt, T, N), f32(di, N),
+            f32(Bt, di, N))
+    scan = lambda *a: mamba_scan(*a, chunk=128, interpret=False)
+    assert "mamba_scan_fwd" in _compile(scan, *args).as_text()
+
+    def loss(*a):
+        y, h_last = scan(*a)
+        return jnp.sum(y) + jnp.sum(h_last)
+
+    assert "mamba_scan_bwd" in _compile(jax.grad(loss, argnums=range(6)), *args).as_text()
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import AxisType
+
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels lower through Mosaic, as on the chip: the backend here is
+    the CPU, whose default is the interpreter, which XLA could partition."""
+    from repro.kernels import interpret
+
+    monkeypatch.setattr(interpret, "on_tpu", lambda: True)
+
+
+def _hymba(layers=2):
+    import dataclasses
+
+    from repro.configs import get_config
+
+    return dataclasses.replace(get_config("hymba-1.5b"), num_layers=layers)
+
+
+def _on(mesh, tree, specs):
+    return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                         sharding=NamedSharding(mesh, s)),
+                        tree, specs, is_leaf=lambda x: isinstance(x, P))
+
+
+def test_hymba_grad_allreduce_step_compiles_on_four_chips(four_chips, mosaic):
+    """The trainer's default step (``grad_allreduce``: XLA partitions the
+    step over data x model) at hymba-1.5b's widths: the scan kernels split
+    themselves over the mesh, which XLA's partitioner cannot do."""
+    from repro.configs.base import RunConfig
+    from repro.dist.sharding import batch_specs
+    from repro.train.trainer import Trainer
+
+    tr = Trainer(_hymba(), RunConfig(sync_mode="grad_allreduce"), mesh=four_chips)
+    params = jax.eval_shape(tr.model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tr.optimizer.init, params)
+    tok = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+    ospecs = {k: (tr._pspecs if k in ("m", "v") else P()) for k in opt}
+    text = tr._step_fn.lower(_on(four_chips, params, tr._pspecs), _on(four_chips, opt, ospecs),
+                             _on(four_chips, batch, batch_specs(batch, four_chips))
+                             ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "mamba_scan_fwd" in text and "mamba_scan_bwd" in text
+
+
+def test_hymba_sharded_prefill_compiles_on_four_chips(four_chips, mosaic):
+    """The serving engine's prefill on its tensor-parallel layout, with an
+    odd prompt length, as ``Engine`` jits it."""
+    from functools import partial
+
+    from repro.dist.sharding import batch_specs, on_mesh, param_specs
+    from repro.models import Model
+
+    model = Model(_hymba())
+    shapes = model.param_shapes()
+    pspecs = param_specs(shapes, four_chips, fsdp=False, attn_fallback="head_dim")
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 301), jnp.int32)}
+    fn = on_mesh(partial(model.prefill, max_len=320), four_chips)
+    text = jax.jit(fn).lower(_on(four_chips, shapes, pspecs),
+                             _on(four_chips, batch, batch_specs(batch, four_chips))
+                             ).compile().as_text()
+    assert "tpu_custom_call" in text and "mamba_scan_fwd" in text
 
 
 @pytest.mark.parametrize("M", [4 << 20, 64 << 20])
