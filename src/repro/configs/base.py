@@ -7,6 +7,42 @@ from typing import Optional, Tuple
 
 __all__ = ["ModelConfig", "ShapeSpec", "INPUT_SHAPES", "RunConfig"]
 
+# Keys of a published Hugging Face config.json, each with the value that the
+# program's own fields imply. A configuration file may hold them beside
+# those fields, so that it states the published model key for key; they
+# are checked when a ModelConfig is made, then dropped, so nothing reads
+# them and ``dataclasses.replace`` does not carry them. A key mapped to
+# None only states the published model.
+PUBLISHED_KEYS = {
+    "hidden_size": lambda c: c.d_model,
+    "num_hidden_layers": lambda c: c.num_layers,
+    "num_attention_heads": lambda c: c.num_heads,
+    "num_key_value_heads": lambda c: c.num_kv_heads,
+    "moe_intermediate_size": lambda c: c.d_ff,
+    "n_routed_experts": lambda c: c.num_experts,
+    "num_experts_per_tok": lambda c: c.experts_per_token,
+    "n_shared_experts": lambda c: c.num_shared_experts,
+    "rms_norm_eps": lambda c: c.norm_eps,
+    "tie_word_embeddings": lambda c: c.tie_embeddings,
+    "hidden_act": lambda c: c.act,
+    "attention_bias": lambda c: c.qkv_bias,
+    "moe_layer_freq": lambda c: 1,              # every layer after the dense ones
+    "num_nextn_predict_layers": lambda c: 0,    # no multi-token prediction layers
+    "scoring_func": lambda c: "sigmoid" if c.dropless else "softmax",
+    "norm_topk_prob": lambda c: True,           # chosen weights always normalised
+    "seq_aux": lambda c: c.dropless,            # the balance loss of dropless routing
+    "n_group": lambda c: 1,                     # no grouped routing
+    "topk_group": lambda c: 1,
+    "q_lora_rank": lambda c: None,              # no query compression
+    "model_type": None,
+    "max_position_embeddings": None,
+    "ep_size": None,
+}
+
+
+def _published():
+    return dataclasses.field(default=None, repr=False, compare=False)
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -20,7 +56,7 @@ class ModelConfig:
     d_model: int
     num_heads: int
     num_kv_heads: int
-    d_ff: int
+    d_ff: int                       # MLP width; the expert width in MoE layers
     vocab_size: int
     head_dim: Optional[int] = None
 
@@ -35,6 +71,25 @@ class ModelConfig:
     # all-to-all) or 'alltoallv' (explicit repro.comm.palltoallv expert
     # parallelism — needs an axis_name threaded to moe_ffn)
     moe_dispatch: str = "einsum"
+    # 'noaux_tc' is DeepSeek-V3's routing: top-k of sigmoid(logits) plus a
+    # per-expert bias that the step moves by load, not by gradient; the
+    # chosen scores, unbiased, normalised and scaled by
+    # routed_scaling_factor. Dropless: the layer holds ``num_experts``
+    # experts of the router's ``router_experts`` (0 = all of them) and
+    # computes its own experts' part of the result. router_aux_coef is then
+    # the sequence-wise balance loss's alpha.
+    topk_method: str = "greedy"     # greedy (GShard, capacity) | noaux_tc (dropless)
+    routed_scaling_factor: float = 1.0
+    router_experts: int = 0
+    # leading layers with a dense MLP of width intermediate_size
+    first_k_dense_replace: int = 0
+    intermediate_size: int = 0
+
+    # --- multi-head latent attention (kv_lora_rank > 0) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- attention ---
     qkv_bias: bool = False
@@ -57,6 +112,7 @@ class ModelConfig:
     frontend_len: int = 0           # frames / patches supplied by the stub
     prefix_len: int = 0             # bidirectional prefix (VLM prefix-LM)
     tie_embeddings: bool = True
+    embed_scale: bool = True        # multiply embeddings by sqrt(d_model)
     act: str = "silu"               # mlp nonlinearity: silu (swiglu) | gelu
     norm_eps: float = 1e-6
     vocab_pad_to: int = 256
@@ -69,6 +125,31 @@ class ModelConfig:
     # --- citation ---
     source: str = ""
 
+    # --- keys of a published Hugging Face config.json (PUBLISHED_KEYS) ---
+    hidden_size: Optional[int] = _published()
+    num_hidden_layers: Optional[int] = _published()
+    num_attention_heads: Optional[int] = _published()
+    num_key_value_heads: Optional[int] = _published()
+    moe_intermediate_size: Optional[int] = _published()
+    n_routed_experts: Optional[int] = _published()
+    num_experts_per_tok: Optional[int] = _published()
+    n_shared_experts: Optional[int] = _published()
+    rms_norm_eps: Optional[float] = _published()
+    tie_word_embeddings: Optional[bool] = _published()
+    hidden_act: Optional[str] = _published()
+    attention_bias: Optional[bool] = _published()
+    model_type: Optional[str] = _published()
+    max_position_embeddings: Optional[int] = _published()
+    ep_size: Optional[int] = _published()
+    moe_layer_freq: Optional[int] = _published()
+    num_nextn_predict_layers: Optional[int] = _published()
+    scoring_func: Optional[str] = _published()
+    norm_topk_prob: Optional[bool] = _published()
+    seq_aux: Optional[bool] = _published()
+    n_group: Optional[int] = _published()
+    topk_group: Optional[int] = _published()
+    q_lora_rank: Optional[int] = _published()
+
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
@@ -77,8 +158,35 @@ class ModelConfig:
             object.__setattr__(self, "block_pattern", (kind,))
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads must be divisible by num_kv_heads")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"unsupported topk_method {self.topk_method!r}")
+        if self.router_experts and self.router_experts < self.num_experts:
+            raise ValueError("router_experts must be at least num_experts (the experts held)")
+        self._check_published_keys()
+
+    def _check_published_keys(self):
+        for key, implied in PUBLISHED_KEYS.items():
+            value = getattr(self, key)
+            if implied is not None and value is not None and value != implied(self):
+                raise ValueError(f"{self.name}: {key}={value!r} disagrees with the program's "
+                                 f"{implied(self)!r}")
+            object.__setattr__(self, key, None)
 
     # ---- derived ----
+
+    @property
+    def mla(self) -> bool:
+        """Multi-head latent attention in place of GQA."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def dropless(self) -> bool:
+        """DeepSeek-V3 routing over ``router_width`` experts, dropless."""
+        return self.topk_method == "noaux_tc"
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -90,8 +198,10 @@ class ModelConfig:
         return max(len(self.block_pattern), len(self.attn_pattern))
 
     def layer_kinds(self) -> list[str]:
-        bp = self.block_pattern
-        return [bp[i % len(bp)] for i in range(self.num_layers)]
+        """Kind of every layer: the leading dense layers ('attn'), then the
+        block pattern repeated."""
+        bp, k = self.block_pattern, self.first_k_dense_replace
+        return ["attn"] * k + [bp[i % len(bp)] for i in range(self.num_layers - k)]
 
     def layer_windows(self) -> list[Optional[int]]:
         ap = self.attn_pattern
@@ -130,6 +240,10 @@ class ModelConfig:
         kinds = self.layer_kinds()
 
         def attn_params():
+            if self.mla:
+                r, nope, rp, vd = (self.kv_lora_rank, self.qk_nope_head_dim,
+                                   self.qk_rope_head_dim, self.v_head_dim)
+                return d * H * (nope + rp) + d * (r + rp) + r + r * H * (nope + vd) + H * vd * d
             p = d * H * hd + 2 * d * KV * hd + H * hd * d
             if self.qkv_bias:
                 p += H * hd + 2 * KV * hd
@@ -147,11 +261,17 @@ class ModelConfig:
 
         for i, kind in enumerate(kinds):
             if kind == "attn":
-                total += attn_params() + mlp_params(self.d_ff)
+                width = self.intermediate_size if i < self.first_k_dense_replace else self.d_ff
+                total += attn_params() + mlp_params(width)
             elif kind == "moe":
-                e = self.experts_per_token if active_only else self.num_experts
+                if active_only:  # the experts held, at uniform load
+                    e = self.experts_per_token * self.num_experts / self.router_width
+                else:
+                    e = self.num_experts
                 total += attn_params() + (e + self.num_shared_experts) * mlp_params(self.d_ff)
-                total += d * self.num_experts  # router
+                total += d * self.router_width  # router
+                if self.dropless:
+                    total += self.router_width  # its correction bias
             elif kind == "mlstm":
                 total += ssm_params()
             elif kind == "slstm":
@@ -167,8 +287,9 @@ class ModelConfig:
         return int(total)
 
     def reduced(self) -> "ModelConfig":
-        """CPU smoke variant of the same family: 2 pattern periods of layers,
-        d_model <= 512, <= 4 experts."""
+        """CPU smoke variant of the same family: 2 pattern periods of layers
+        (the leading dense ones among them), d_model <= 512, <= 4 experts (of
+        a router <= 8 wide where the layer holds a share)."""
         period = self.pattern_period
         n_layers = min(self.num_layers, 2 * period)
         d = min(self.d_model, 256)
@@ -188,7 +309,13 @@ class ModelConfig:
             d_ff=min(self.d_ff, 512) if self.d_ff else self.d_ff,
             vocab_size=min(self.vocab_size, 1024),
             num_experts=min(self.num_experts, 4),
+            router_experts=min(self.router_experts, 8),
             experts_per_token=min(self.experts_per_token, 2),
+            intermediate_size=min(self.intermediate_size, 512),
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             num_shared_experts=min(self.num_shared_experts, 1),
             moe_group_size=64,
             encoder_layers=enc,

@@ -14,10 +14,12 @@ parameters (``param_specs``)
     kv-heads for wk/wv).  Non-divisible head counts (hymba's 25, MQA's 1)
     fall back per ``attn_fallback``: ``"replicate"`` (train/prefill — a
     head_dim shard would all-reduce score blocks every layer) or
-    ``"head_dim"`` (decode — serving memory wins).
+    ``"head_dim"`` (decode — serving memory wins).  Latent attention:
+    ``wkv_b`` (r, H, nope + v) by heads like wq; ``wkv_a`` (d, r + rope)
+    makes the latent every head reads, so it stays whole on ``model``.
   * MoE: the expert dim shards on ``model`` when divisible (qwen3's 128
     experts), else the expert FFN width does (mixtral's 8 < 16); shared
-    experts follow the dense-MLP rule.
+    experts follow the dense-MLP rule; the routing bias (1-D) replicates.
   * dense matmuls: the FFN-width / output-feature dim shards on ``model``.
   * FSDP (``fsdp=True``, the training default) additionally shards the
     d_model-side dim over the data axes — ('pod','data') jointly when
@@ -153,6 +155,10 @@ def param_specs(shapes: Any, mesh, *, fsdp: bool = True,
                 fsdp_put(-1)
             else:                                   # bq/bk/bv (H|KV, hd)
                 head_rule(-2, -1)
+        elif "attn" in names and leaf_key == "wkv_a":  # MLA (d, r + rope): the latent
+            fsdp_put(-2)                                # every head reads: replicated on model
+        elif "attn" in names and leaf_key == "wkv_b":  # MLA (r, H, nope + v)
+            head_rule(-2, -1)
         elif in_moe and leaf_key == "router":       # (d, E)
             ent[-1] = ax.tp_if_divisible(dims[-1])
             fsdp_put(-2)

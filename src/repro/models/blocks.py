@@ -4,10 +4,15 @@ heterogeneous layer patterns (see transformer.py).
 
 Kinds:
   attn    pre-norm GQA attention + MLP            (dense archs)
-  moe     pre-norm GQA attention + MoE FFN        (mixtral / qwen3 / moonshot)
+  moe     pre-norm GQA attention + MoE FFN        (mixtral / qwen3: GShard
+          capacity routing; moonshot: dropless DeepSeek-V3 routing over one
+          share of the experts, plus shared experts)
   mlstm   matrix-LSTM mixer                       (xLSTM)
   slstm   scalar-LSTM mixer                       (xLSTM)
   hybrid  parallel attention + mamba heads + MLP  (hymba)
+
+Where ``cfg.kv_lora_rank`` is set, ``attn`` and ``moe`` blocks attend with
+multi-head latent attention (``mla.py``) instead of GQA.
 
 Caches (prefill/decode) are dict pytrees whose structure depends on kind.
 """
@@ -18,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import mla
 from . import moe as moe_lib
 from . import ssm
 from .layers import (
@@ -45,12 +51,16 @@ def attn_spec_for(cfg, window: Optional[int], causal: bool = True) -> AttnSpec:
     )
 
 
-def init_block(key, cfg, kind: str, window: Optional[int], *, cross: bool = False, causal: bool = True, dtype=jnp.bfloat16):
+def init_block(key, cfg, kind: str, window: Optional[int], *, cross: bool = False, causal: bool = True,
+               dtype=jnp.bfloat16, d_ff: Optional[int] = None):
+    """``d_ff`` overrides the MLP width (the leading dense layers')."""
     d = cfg.d_model
     ks = jax.random.split(key, 8)
     spec = attn_spec_for(cfg, window, causal)
     p = {"norm1": init_rms_norm(d)}
-    if kind in ("attn", "moe", "hybrid"):
+    if kind in ("attn", "moe") and cfg.mla:
+        p["attn"] = mla.init_mla(ks[0], cfg, dtype)
+    elif kind in ("attn", "moe", "hybrid"):
         p["attn"] = init_attention(ks[0], d, spec, dtype)
     if kind == "hybrid":
         p["ssm"] = ssm.init_mamba(ks[1], cfg, dtype)
@@ -65,7 +75,7 @@ def init_block(key, cfg, kind: str, window: Optional[int], *, cross: bool = Fals
         if kind == "moe":
             p["moe"] = moe_lib.init_moe(ks[2], cfg, dtype)
         else:
-            p["mlp"] = init_mlp(ks[2], d, cfg.d_ff, cfg.act, dtype)
+            p["mlp"] = init_mlp(ks[2], d, d_ff or cfg.d_ff, cfg.act, dtype)
     if kind == "moe" and not cfg.d_ff:
         raise ValueError("moe blocks need d_ff (expert width)")
     if cross:
@@ -122,15 +132,23 @@ def apply_block(
     cross_inputs=None,
     axis_name=None,
 ):
-    """Returns (x, new_cache, aux_loss). ``axis_name`` names the mesh axis
-    for explicit MoE expert dispatch (``cfg.moe_dispatch='alltoallv'``);
-    None keeps the dense einsum formulation."""
+    """Returns (x, new_cache, aux_loss, expert_load). ``axis_name`` names
+    the mesh axis for explicit MoE expert dispatch
+    (``cfg.moe_dispatch='alltoallv'``); None keeps the dense einsum
+    formulation. ``expert_load`` is the per-expert token count of a
+    dropless MoE layer (over the router's experts), else None."""
     spec = attn_spec_for(cfg, window, causal)
     aux = jnp.zeros((), jnp.float32)
+    load = None
     new_cache = {}
     h = rms_norm(p["norm1"], x, cfg.norm_eps)
 
-    if kind in ("attn", "moe", "hybrid"):
+    if kind in ("attn", "moe") and cfg.mla:
+        if mode != "train":
+            raise NotImplementedError("latent attention has no latent KV cache yet: training only")
+        with jax.named_scope("mixer/mla"):
+            x = x + mla.mla_attention(p["attn"], h, cfg, positions)
+    elif kind in ("attn", "moe", "hybrid"):
         attn_cache = cache.get("attn") if cache else None
         with jax.named_scope("mixer/attn"):
             y, ac = attention(
@@ -198,13 +216,17 @@ def apply_block(
     with jax.named_scope("mlp"):
         if "mlp" in p:
             x = x + mlp(p["mlp"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg.act)
+        elif "moe" in p and cfg.dropless:
+            y, a, load = moe_lib.dropless_ffn(p["moe"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg)
+            x = x + y
+            aux = aux + a
         elif "moe" in p:
             y, a = moe_lib.moe_ffn(p["moe"], rms_norm(p["norm2"], x, cfg.norm_eps), cfg,
                                    axis_name=axis_name)
             x = x + y
             aux = aux + a
 
-    return x, (new_cache if new_cache else None), aux
+    return x, (new_cache if new_cache else None), aux, load
 
 
 def _grow_cache(cache: dict, max_len: int, spec: AttnSpec) -> dict:

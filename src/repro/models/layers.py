@@ -155,7 +155,8 @@ def _out_proj(out, wo):
 
 
 def _sdpa(q, k, v, mask, spec: AttnSpec):
-    """q: (B,T,H,hd); k,v: (B,S,KV,hd); mask broadcastable to (B,T,S)."""
+    """q: (B,T,H,hd); k: (B,S,KV,hd); v: (B,S,KV,hd_v); mask broadcastable
+    to (B,T,S)."""
     B, T, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -167,7 +168,7 @@ def _sdpa(q, k, v, mask, spec: AttnSpec):
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskh->btkgh", w.astype(v.dtype), v)
-    return out.reshape(B, T, H, hd)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 # S at which train/prefill attention switches to the memory-efficient
@@ -226,14 +227,14 @@ def _chunked_sdpa(q, k, v, spec: AttnSpec, prefix_len: int, block: int = _CHUNK_
         )
         return (o, m_new, l), None
 
-    o0 = jnp.zeros((B, KV, G, T, hd), jnp.float32)
+    o0 = jnp.zeros((B, KV, G, T, v.shape[-1]), jnp.float32)
     m0 = jnp.full((B, KV, G, T), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, KV, G, T), jnp.float32)
     (o, m, l), _ = jax.lax.scan(
         body, (o0, m0, l0), (kb, vb, jnp.arange(nb) * block)
     )
     out = o / jnp.maximum(l[..., None], 1e-30)
-    out = jnp.moveaxis(out, 3, 1).reshape(B, T, H, hd)
+    out = jnp.moveaxis(out, 3, 1).reshape(B, T, H, v.shape[-1])
     return out.astype(q.dtype)
 
 

@@ -10,7 +10,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig, ShapeSpec
 from .layers import cross_entropy_loss
-from .transformer import apply_lm, init_decode_cache, init_lm
+from .transformer import apply_lm, init_decode_cache, init_lm, update_router_bias
 
 __all__ = ["Model"]
 
@@ -31,7 +31,11 @@ class Model:
     # ---- forward ----------------------------------------------------------
 
     def forward(self, params, batch, *, remat: bool = False):
-        logits, _, aux = apply_lm(
+        logits, aux, _ = self._train_forward(params, batch, remat)
+        return logits, aux
+
+    def _train_forward(self, params, batch, remat: bool):
+        logits, _, aux, load = apply_lm(
             params,
             self.cfg,
             tokens=batch["tokens"],
@@ -39,21 +43,34 @@ class Model:
             mode="train",
             remat=remat,
         )
-        return logits, aux
+        return logits, aux, load
 
     def loss(self, params, batch, *, remat: bool = False):
-        logits, aux = self.forward(params, batch, remat=remat)
+        """(nll + aux, metrics). A model with dropless MoE layers also
+        reports ``expert_load``, (MoE layers, router experts) token counts:
+        what ``update_router_bias`` moves the routing bias by."""
+        logits, aux, load = self._train_forward(params, batch, remat)
         with jax.named_scope("head"):
             labels = jnp.minimum(batch["labels"], self.cfg.padded_vocab - 1)
             nll = cross_entropy_loss(logits, labels, batch.get("loss_mask"))
-        return nll + aux, {"nll": nll, "aux": aux}
+        metrics = {"nll": nll, "aux": aux}
+        if load is not None:
+            metrics["expert_load"] = load
+        return nll + aux, metrics
+
+    def update_router_bias(self, new_params, params, metrics):
+        """The step's last act: the routing bias moved by the step's load;
+        ``new_params`` unchanged for a model without dropless MoE layers."""
+        if "expert_load" not in metrics:
+            return new_params
+        return update_router_bias(new_params, params, metrics["expert_load"], self.cfg)
 
     # ---- serving ----------------------------------------------------------
 
     def prefill(self, params, batch, *, max_len: int):
         if self.cfg.frontend == "vision":
             max_len = max_len + self.cfg.prefix_len  # cache holds the prefix too
-        logits, caches, _ = apply_lm(
+        logits, caches, _, _ = apply_lm(
             params,
             self.cfg,
             tokens=batch["tokens"],
@@ -66,7 +83,7 @@ class Model:
     def decode_step(self, params, tokens, caches, cur_pos):
         """tokens (B,1) int32; cur_pos scalar int32 (absolute position of the
         new token). Returns (logits (B,1,V), new_caches)."""
-        logits, caches, _ = apply_lm(
+        logits, caches, _, _ = apply_lm(
             params,
             self.cfg,
             tokens=tokens,

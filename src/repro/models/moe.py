@@ -19,6 +19,16 @@ Two dispatch transports (``cfg.moe_dispatch``):
   two agree to summation order.
 
 Shared experts (DeepSeek/Moonlight style) run densely for every token.
+
+DeepSeek-V3 routing (``cfg.dropless``; :func:`dropless_ffn`) replaces all
+of the above: sigmoid scores over the router's ``cfg.router_width``
+experts, the top-k of scores plus a per-expert correction bias, weights
+from the unbiased chosen scores. Nothing is dropped and nothing is padded
+to a capacity. The layer holds ``cfg.num_experts`` consecutive experts from
+``first_expert`` (one chip's share under expert parallelism): the choices
+of held experts are sorted into ragged groups for one grouped matmul; the
+choices of experts held elsewhere add nothing here. The shared experts run
+for every token.
 """
 from __future__ import annotations
 
@@ -29,18 +39,20 @@ from jax import lax
 
 from .layers import _norm_init, down_proj
 
-__all__ = ["init_moe", "moe_ffn", "expert_partition"]
+__all__ = ["init_moe", "moe_ffn", "dropless_ffn", "route_sigmoid", "expert_partition"]
 
 
 def init_moe(key, cfg, dtype=jnp.bfloat16):
     d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": _norm_init(ks[0], (d, E), d**-0.5, jnp.float32),
+        "router": _norm_init(ks[0], (d, cfg.router_width), d**-0.5, jnp.float32),
         "w_gate": _norm_init(ks[1], (E, d, f), d**-0.5, dtype),
         "w_up": _norm_init(ks[2], (E, d, f), d**-0.5, dtype),
         "w_down": _norm_init(ks[3], (E, f, d), f**-0.5, dtype),
     }
+    if cfg.dropless:
+        p["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
     if cfg.num_shared_experts:
         fs = f * cfg.num_shared_experts
         kss = jax.random.split(ks[4], 3)
@@ -230,3 +242,61 @@ def _moe_ffn_alltoallv(p, x, cfg, axis_name):
     ce_g = lax.pmean(ce, axis_name)
     aux = E * jnp.sum(me_g * ce_g) * cfg.router_aux_coef
     return y, aux
+
+
+def route_sigmoid(p, x, cfg):
+    """DeepSeek-V3's router on x (B, T, D): float32 logits over the
+    router's experts, ``scores = sigmoid(logits)``, the choice the top-k of
+    ``scores + bias`` (the bias steers the choice and takes no gradient),
+    the weights the chosen scores, normalised and scaled. Returns
+    (expert ids (B, T, k), weights (B, T, k) f32, per-expert load (E,) f32,
+    the sequence-wise balance loss)."""
+    E, k = cfg.router_width, cfg.experts_per_token
+    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), p["router"])
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(p["router_bias"]), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    chosen = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=2)   # (B, T, E)
+    load = jnp.sum(chosen, axis=(0, 1))
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.router_aux_coef:
+        # DeepSeek-V3 eq. 17-20, per sequence: f_i = E/(k T) * tokens
+        # choosing i; P_i = mean over t of s_i,t / sum_j s_j,t
+        T = x.shape[1]
+        f = jnp.sum(chosen, axis=1) * (E / (k * T))
+        P = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=1)
+        aux = cfg.router_aux_coef * jnp.mean(jnp.sum(f * P, axis=-1))
+    return idx, w, load, aux
+
+
+def dropless_ffn(p, x, cfg, *, first_expert: int = 0):
+    """x: (B, T, D) -> (out, balance loss, per-expert load over the router's
+    experts). The layer's part of the routed experts' result (the experts
+    ``first_expert`` .. + ``cfg.num_experts``) plus the shared experts."""
+    from ..kernels.ops import grouped_matmul
+
+    B, T, D = x.shape
+    E_held, k = cfg.num_experts, cfg.experts_per_token
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            idx, w, load, aux = route_sigmoid(p, x, cfg)
+        with jax.named_scope("dispatch"):
+            local = idx.reshape(-1) - first_expert
+            held = (local >= 0) & (local < E_held)
+            group = jnp.where(held, local, E_held)       # held groups first, the rest last
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.sum(jax.nn.one_hot(group, E_held + 1, dtype=jnp.int32), axis=0)[:E_held]
+            rows = jnp.take(x.reshape(B * T, D), order // k, axis=0)
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(grouped_matmul(rows, p["w_gate"], sizes)) * grouped_matmul(rows, p["w_up"], sizes)
+            out = grouped_matmul(h, p["w_down"], sizes)  # rows past the held groups are zero
+        with jax.named_scope("combine"):
+            back = jnp.take(out, jnp.argsort(order), axis=0).reshape(B * T, k, D)
+            wk = (w.reshape(B * T, k) * held.reshape(B * T, k)).astype(x.dtype)
+            y = jnp.einsum("tkd,tk->td", back, wk, preferred_element_type=jnp.float32)
+            y = y.astype(x.dtype).reshape(B, T, D)
+        if "shared" in p:
+            with jax.named_scope("shared"):
+                y = y + _shared_out(p, x)
+    return y, aux, load

@@ -5,7 +5,9 @@ Layers are grouped into *superblocks* of length P = lcm(|block_pattern|,
 slot, and ``lax.scan`` iterates over ``num_layers // P`` superblocks with
 stacked parameters. Heterogeneous stacks (xLSTM's 7:1 mLSTM:sLSTM, gemma3's
 5:1 local:global) therefore compile to ONE body — HLO size and compile time
-are depth-independent. ``num_layers % P`` leftover layers run unscanned.
+are depth-independent. ``num_layers % P`` leftover layers run unscanned, as
+do leading dense layers (``cfg.first_k_dense_replace``, kept under
+``lead`` with their own MLP width) ahead of the scan.
 """
 from __future__ import annotations
 
@@ -21,7 +23,7 @@ from ..dist.hints import hint
 from .blocks import apply_block, init_block, init_block_cache
 from .layers import embed_tokens, init_embedding, init_rms_norm, rms_norm, unembed
 
-__all__ = ["StackLayout", "init_lm", "apply_lm", "init_decode_cache"]
+__all__ = ["StackLayout", "init_lm", "apply_lm", "init_decode_cache", "update_router_bias"]
 
 
 class StackLayout:
@@ -29,6 +31,7 @@ class StackLayout:
 
     def __init__(self, cfg, *, encoder: bool = False):
         self.cfg = cfg
+        self.lead = 0
         if encoder:
             self.kinds = ["attn"] * cfg.encoder_layers
             self.windows = [None] * cfg.encoder_layers
@@ -37,9 +40,10 @@ class StackLayout:
         else:
             bp, ap = cfg.block_pattern, cfg.attn_pattern
             self.period = math.lcm(len(bp), len(ap))
-            self.num_layers = cfg.num_layers
-            self.kinds = cfg.layer_kinds()
-            self.windows = cfg.layer_windows()
+            self.lead = cfg.first_k_dense_replace
+            self.num_layers = cfg.num_layers - self.lead   # scanned and tail layers
+            self.kinds = cfg.layer_kinds()[self.lead:]
+            self.windows = cfg.layer_windows()[self.lead:]
         self.num_super = self.num_layers // self.period
         self.tail = self.num_layers % self.period
 
@@ -79,7 +83,14 @@ def _init_stack(key, cfg, layout: StackLayout, *, cross: bool, causal: bool):
                 dtype=dt,
             )
         )
-    return {"blocks": blocks, "tail": tail}
+    out = {"blocks": blocks, "tail": tail}
+    if layout.lead:
+        out["lead"] = [
+            init_block(jax.random.fold_in(key, 20_000 + j), cfg, "attn", cfg.layer_windows()[j],
+                       cross=cross, causal=causal, dtype=dt, d_ff=cfg.intermediate_size)
+            for j in range(layout.lead)
+        ]
+    return out
 
 
 def init_lm(key, cfg):
@@ -115,9 +126,10 @@ def _apply_stack(
     remat: bool = False,
     axis_name=None,
 ):
-    """Returns (x, new_caches, aux). Caches: {'blocks': [...], 'tail': [...]}
-    ``axis_name`` routes MoE expert dispatch over that mesh axis (see
-    ``apply_block``)."""
+    """Returns (x, new_caches, aux, expert_load). Caches: {'blocks': [...],
+    'tail': [...]}. ``axis_name`` routes MoE expert dispatch over that mesh
+    axis (see ``apply_block``). ``expert_load`` stacks the per-expert loads
+    of the dropless MoE layers in layer order (None without them)."""
     P = layout.period
     kinds, wins = layout.kinds, layout.windows
     run_block = partial(
@@ -135,25 +147,36 @@ def _apply_stack(
     def body(x, xs):
         bs, cs = xs
         aux = jnp.zeros((), jnp.float32)
-        new_cs = []
+        new_cs, loads = [], []
         for i in range(P):
-            x, nc, a = run_block(bs[i], x, kind=kinds[i], window=wins[i], cache=None if cs is None else cs[i])
+            x, nc, a, ld = run_block(bs[i], x, kind=kinds[i], window=wins[i], cache=None if cs is None else cs[i])
             x = hint(x, "btd_res")  # optional sequence-parallel residual
             aux = aux + a
             new_cs.append(nc)
+            if ld is not None:
+                loads.append(ld)
         if mode == "train":
-            return x, aux
+            return x, (aux, loads)
         return x, (new_cs, aux)
 
     if remat and mode == "train":
         body = jax.checkpoint(body, prevent_cse=False)
 
     aux_total = jnp.zeros((), jnp.float32)
+    loads = []
+    for j, lp in enumerate(stack_params.get("lead", [])):
+        lead_block = partial(run_block, kind="attn", window=cfg.layer_windows()[j], cache=None)
+        if remat and mode == "train":
+            lead_block = jax.checkpoint(lead_block, prevent_cse=False)
+        x, _, a, _ = lead_block(lp, x)
+        aux_total = aux_total + a
     new_caches = {"blocks": None, "tail": []}
     if layout.num_super:
         xs = (stack_params["blocks"], caches["blocks"] if caches else None)
         if mode == "train":
-            x, auxs = lax.scan(body, x, xs)
+            x, (auxs, slot_loads) = lax.scan(body, x, xs)
+            if slot_loads:  # (num_super, E) per slot -> layer order
+                loads.append(jnp.stack(slot_loads, axis=1).reshape(-1, slot_loads[0].shape[-1]))
         else:
             x, (blk_caches, auxs) = lax.scan(body, x, xs)
             new_caches["blocks"] = blk_caches
@@ -161,10 +184,13 @@ def _apply_stack(
     for j, tp in enumerate(stack_params["tail"]):
         i = layout.num_super * P + j
         tc = caches["tail"][j] if caches else None
-        x, nc, a = run_block(tp, x, kind=kinds[i % P], window=wins[i % P], cache=tc)
+        x, nc, a, ld = run_block(tp, x, kind=kinds[i % P], window=wins[i % P], cache=tc)
         aux_total = aux_total + a
         new_caches["tail"].append(nc)
-    return x, (new_caches if mode != "train" else None), aux_total
+        if ld is not None:
+            loads.append(ld[None])
+    load = jnp.concatenate(loads) if loads else None
+    return x, (new_caches if mode != "train" else None), aux_total, load
 
 
 def apply_lm(
@@ -185,7 +211,8 @@ def apply_lm(
     (B, prefix, D); audio encdec consumes ``embeds`` (B, frames, D) through
     the encoder. decode: ``tokens`` (B, 1) + ``caches`` + scalar ``cur_pos``.
 
-    Returns (logits_f32, new_caches, aux).
+    Returns (logits_f32, new_caches, aux, expert_load); ``expert_load``
+    (dropless MoE layers x router experts) is None for other models.
     """
     layout = StackLayout(cfg)
     dt = _dtype(cfg)
@@ -200,7 +227,7 @@ def apply_lm(
             assert embeds is not None, "encdec needs frontend embeddings"
             enc_layout = StackLayout(cfg, encoder=True)
             h = embeds.astype(dt)
-            h, _, _ = _apply_stack(
+            h, _, _, _ = _apply_stack(
                 params["encoder"], h, cfg, enc_layout, mode="train", causal=False, remat=remat
             )
             cross_inputs = rms_norm(params["enc_norm"], h, cfg.norm_eps)
@@ -213,11 +240,13 @@ def apply_lm(
             prefix_len = embeds.shape[1]
         else:
             prefix_len = cfg.prefix_len
-    else:
+    elif cfg.embed_scale:
         x = embed_tokens(params["embed"], tokens) * jnp.asarray(cfg.d_model**0.5, dt)
+    else:
+        x = embed_tokens(params["embed"], tokens)
 
     x = hint(x, "btd")
-    x, new_caches, aux = _apply_stack(
+    x, new_caches, aux, load = _apply_stack(
         params["decoder"],
         x,
         cfg,
@@ -236,7 +265,40 @@ def apply_lm(
         if mode in ("train", "prefill") and prefix_len and cfg.frontend == "vision":
             x = x[:, prefix_len:]
         logits = hint(unembed(params["embed"], x), "btv")
-    return logits, new_caches, aux
+    return logits, new_caches, aux, load
+
+
+# gamma of DeepSeek-V3's bias update (arXiv:2412.19437; published configs
+# do not give it)
+BIAS_UPDATE_SPEED = 1e-3
+
+
+def update_router_bias(new_params, params, load, cfg):
+    """DeepSeek-V3's auxiliary-loss-free balancing, after an optimizer step:
+    each dropless MoE layer's correction bias becomes its value before the
+    step plus ``BIAS_UPDATE_SPEED * sign(mean load - load)``, whatever the
+    optimizer made of the leaf (its gradient is zero). ``load`` is
+    ``apply_lm``'s, in layer order: the scanned superblocks, then the tail."""
+    layout = StackLayout(cfg)
+    P, n = layout.period, layout.num_super
+    step = BIAS_UPDATE_SPEED * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+    dec, old = dict(new_params["decoder"]), params["decoder"]
+    slots = [i for i in range(P) if "router_bias" in old["blocks"][i].get("moe", {})] if n else []
+    if slots:
+        per_slot = step[: n * len(slots)].reshape(n, len(slots), -1)
+        blocks = list(dec["blocks"])
+        for j, i in enumerate(slots):
+            moe = dict(blocks[i]["moe"], router_bias=old["blocks"][i]["moe"]["router_bias"] + per_slot[:, j])
+            blocks[i] = dict(blocks[i], moe=moe)
+        dec["blocks"] = blocks
+    rest = step[n * len(slots):]
+    tail = list(dec["tail"])
+    for j, tp in enumerate(old["tail"]):
+        if "router_bias" in tp.get("moe", {}):
+            tail[j] = dict(tail[j], moe=dict(tail[j]["moe"], router_bias=tp["moe"]["router_bias"] + rest[0]))
+            rest = rest[1:]
+    dec["tail"] = tail
+    return dict(new_params, decoder=dec)
 
 
 def init_decode_cache(cfg, batch: int, max_len: int):

@@ -52,6 +52,11 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, *, mesh=None, max_len: int = 0,
                  distribute: bool = False, double_buffer: bool = False,
                  drain_dir: Optional[str] = None):
+        if cfg.mla:
+            raise NotImplementedError(
+                f"{cfg.name}: multi-head latent attention needs a latent KV cache (the "
+                "compressed c_kv and the shared RoPE key) for prefill and decode; the "
+                "engine has none yet")
         self.cfg = cfg
         self.model = Model(cfg)
         self.mesh = mesh

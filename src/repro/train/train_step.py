@@ -97,7 +97,7 @@ def _grad_fn(model, run_cfg: RunConfig, grad_specs=None):
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         )
         grads, (losses, metricss) = jax.lax.scan(body, zeros, _microbatch(batch, k))
-        metrics = jax.tree.map(jnp.mean, metricss)
+        metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0), metricss)
         return jnp.mean(losses), metrics, grads
 
     return compute
@@ -105,7 +105,11 @@ def _grad_fn(model, run_cfg: RunConfig, grad_specs=None):
 
 def _apply_update(optimizer: Optimizer, lr_fn: Callable, grads, opt_state, params):
     """Clip, learning rate and optimizer update of every step maker, under
-    one ``optimizer`` scope. Returns (params, opt_state, grad_norm, lr)."""
+    one ``optimizer`` scope. Returns (params, opt_state, grad_norm, lr).
+    Each step maker then hands the new parameters, the old ones and the
+    step's metrics to ``model.update_router_bias``: a routing bias is a
+    buffer moved by load, which the optimizer's result for it does not
+    touch."""
     with jax.named_scope("optimizer"):
         grads, gnorm = clip_by_global_norm(grads, 1.0)
         lr = lr_fn(opt_state["step"])
@@ -122,10 +126,10 @@ def make_train_step(model, run_cfg: RunConfig, optimizer: Optimizer, lr_fn: Call
 
     def train_step(params, opt_state, batch):
         loss, metrics, grads = compute(params, batch)
-        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
+        new, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update(metrics)
-        return params, opt_state, out
+        return model.update_router_bias(new, params, out), opt_state, out
 
     return train_step
 
@@ -182,11 +186,11 @@ def make_bcast_train_step(
         with jax.named_scope("grad_sync"):
             grads = bcast_sync(grads)
         # deterministic, identical update on every rank
-        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
+        new, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         loss = jax.lax.pmean(loss, dp)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: jax.lax.pmean(v, dp) for k, v in metrics.items()})
-        return params, opt_state, out
+        return model.update_router_bias(new, params, out), opt_state, out
 
     return _wrap_dp_step(local_step, mesh, dp)
 
@@ -471,12 +475,12 @@ def make_compressed_allreduce_train_step(
         )
         with jax.named_scope("grad_sync"):
             grads = jax.tree.map(lambda g: g / n_dp, synced)
-        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
+        new, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         opt_state = dict(opt_state, ef=new_ef)
         loss = jax.lax.pmean(loss, dp)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: jax.lax.pmean(v, dp) for k, v in metrics.items()})
-        return params, opt_state, out
+        return model.update_router_bias(new, params, out), opt_state, out
 
     return _wrap_dp_step(local_step, mesh, dp)
 
@@ -540,11 +544,11 @@ def make_degraded_psum_train_step(
 
         with jax.named_scope("grad_sync"):
             grads = jax.tree.map(survivor_mean, grads)
-        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
+        new, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         loss = survivor_mean(loss)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: survivor_mean(v) for k, v in metrics.items()})
-        return params, opt_state, out
+        return model.update_router_bias(new, params, out), opt_state, out
 
     return _wrap_dp_step(local_step, mesh, dp)
 
@@ -574,12 +578,12 @@ def _make_comm_sync_step(model, run_cfg, mesh, sync, optimizer, lr_fn, *, mode,
         with jax.named_scope("grad_sync"):
             grads = sync(grads, axes, inter_pod_axes)
             grads = jax.tree.map(lambda g: g / n_dp, grads)
-        params, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
+        new, opt_state, gnorm, lr = _apply_update(optimizer, lr_fn, grads, opt_state, params)
         if post_update is not None:
-            params = post_update(params, axes, inter_pod_axes)
+            new = post_update(new, axes, inter_pod_axes)
         loss = jax.lax.pmean(loss, dp)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: jax.lax.pmean(v, dp) for k, v in metrics.items()})
-        return params, opt_state, out
+        return model.update_router_bias(new, params, out), opt_state, out
 
     return _wrap_dp_step(local_step, mesh, dp)

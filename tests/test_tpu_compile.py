@@ -6,7 +6,9 @@ is described but not attached, and refuses what the chip would refuse
 either. The widths are the ones the system uses: a 64 MiB f32 gradient
 bucket in 16 chunks, the serving engine's 4 MiB bf16 weight bucket, and
 hymba-1.5b's Mamba scan over a batch of 2 x 2048, alone and inside the
-trainer's step and the engine's prefill on four chips.
+trainer's step and the engine's prefill on four chips; Moonlight-16B-A3B's
+latent attention through the splash kernel, and its training step cut to
+two layers on one chip and on four.
 
 This is the only test file that describes a chip. The topology is described
 inside a fixture, never on import: only one process at a time may load the
@@ -107,6 +109,47 @@ def test_mamba_scan_compiles(one_chip, Bt, T, di):
     assert "mamba_scan_bwd" in _compile(jax.grad(loss, argnums=range(6)), *args).as_text()
 
 
+def test_mla_splash_attention_compiles(one_chip):
+    """The splash attention kernel at Moonlight's latent-attention widths
+    (16 heads, q and k 192 wide, v 128) over 2 x 8192, forward and
+    backward."""
+    from repro.kernels.ops import causal_attention
+
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    q, k, v = bf16(2, 8192, 16, 192), bf16(2, 8192, 16, 192), bf16(2, 8192, 16, 128)
+    _compile(causal_attention, q, k, v)
+    _compile(jax.grad(lambda *a: jnp.sum(causal_attention(*a).astype(jnp.float32)), argnums=(0, 1, 2)),
+             q, k, v)
+
+
+def test_moonlight_step_compiles_on_one_chip(one_chip, mosaic):
+    """The trainer's step at Moonlight-16B-A3B's widths and the benchmark
+    cell's batch of 2 x 8192, cut as the cell cuts it (8 of 64 experts held,
+    a vocabulary of 20,480) and to the dense layer and one MoE layer:
+    latent attention through the splash kernel, the held experts' grouped
+    matmul, all within one chip's memory."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import RunConfig
+    from repro.train.trainer import Trainer
+
+    cfg = dataclasses.replace(get_config("moonshot-v1-16b-a3b"), num_layers=2, vocab_size=20480,
+                              num_experts=8, router_experts=64)
+    mesh = Mesh(np.array([one_chip.device_set.pop()]).reshape(1, 1), ("data", "model"))
+    tr = Trainer(cfg, RunConfig(), mesh=mesh)
+    rep = P()
+    params = jax.eval_shape(tr.model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tr.optimizer.init, params)
+    tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    on = lambda tree: _on(mesh, tree, jax.tree.map(lambda _: rep, tree))  # noqa: E731
+    compiled = tr._step_fn.lower(on(params), on(opt), on({"tokens": tok, "labels": tok})).compile()
+    text = compiled.as_text()
+    assert "splash" in text and "%gmm." in text and "%tgmm." in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75 * 2**30
+
+
 @pytest.fixture(scope="module")
 def four_chips(topo):
     from jax.sharding import AxisType
@@ -176,6 +219,32 @@ def test_hymba_sharded_prefill_compiles_on_four_chips(four_chips, mosaic):
                              _on(four_chips, batch, batch_specs(batch, four_chips))
                              ).compile().as_text()
     assert "tpu_custom_call" in text and "mamba_scan_fwd" in text
+
+
+def test_moonlight_step_compiles_on_four_chips(four_chips, mosaic):
+    """The trainer's default step over data x model at Moonlight-16B-A3B's
+    widths, cut to the dense layer and one MoE layer: the splash attention
+    and grouped-matmul kernels split themselves over the mesh, which XLA's
+    partitioner cannot do."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import RunConfig
+    from repro.dist.sharding import batch_specs
+    from repro.train.trainer import Trainer
+
+    cfg = dataclasses.replace(get_config("moonshot-v1-16b-a3b"), num_layers=2, vocab_size=20480,
+                              num_experts=8, router_experts=64)
+    tr = Trainer(cfg, RunConfig(sync_mode="grad_allreduce"), mesh=four_chips)
+    params = jax.eval_shape(tr.model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tr.optimizer.init, params)
+    tok = jax.ShapeDtypeStruct((4, 1024), jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+    ospecs = {k: (tr._pspecs if k in ("m", "v") else P()) for k in opt}
+    text = tr._step_fn.lower(_on(four_chips, params, tr._pspecs), _on(four_chips, opt, ospecs),
+                             _on(four_chips, batch, batch_specs(batch, four_chips))
+                             ).compile().as_text()
+    assert "splash" in text and "%gmm." in text and "%tgmm." in text
 
 
 @pytest.mark.parametrize("M", [4 << 20, 64 << 20])
