@@ -14,6 +14,7 @@ from . import interpret
 from .flash_attention import flash_attention as _flash
 from .interpret import on_tpu, resolve_interpret
 from .mamba_scan import mamba_scan as _mamba_scan
+from .mlstm_scan import mlstm_scan as _mlstm_scan
 from .param_update import mix as _mix, scaled_add as _scaled_add
 from .quantize import (
     BLOCK_ELEMS,
@@ -33,6 +34,7 @@ __all__ = [
     "quantize_blocks",
     "dequantize_blocks",
     "mamba_scan",
+    "mlstm_scan",
     "grouped_matmul",
     "causal_attention",
 ]
@@ -144,6 +146,35 @@ def mamba_scan(dt, x, Bm, Cm, A, h0, *, chunk: int, interpret: Optional[bool] = 
     return jax.shard_map(scan, in_specs=(seq, seq, bc, bc, P(chans, None), hs),
                          out_specs=(seq, hs), axis_names=set(split),
                          check_vma=False)(dt, x, Bm, Cm, A, h0)
+
+
+def mlstm_scan(q, k, v, lf, li, C0, n0, *, chunk: int, interpret: Optional[bool] = None):
+    """The mLSTM's chunkwise recurrence: q, k, v (B, T, H, hd); lf, li
+    (B, T, H) log forget and input gates; C0 (B, H, hd, hd), n0 (B, H, hd)
+    the state entering. Returns (h (B, T, H, hd) in q's dtype, (C, n) the
+    final state, f32). Chunks of about ``chunk`` steps; any T and hd.
+
+    Where the ambient mesh has axes of more than one device that are not
+    already manual, the scan splits itself with ``jax.shard_map``, as
+    :func:`mamba_scan` does: rows of the batch over those axes but the
+    model axis, heads over the model axis, where they divide. Rows and
+    heads are independent."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ..dist.topology import TP_AXIS
+
+    scan = functools.partial(_mlstm_scan, chunk=chunk, interpret=resolve_interpret(interpret))
+    mesh, split = _split_axes()
+    if not split:
+        return scan(q, k, v, lf, li, C0, n0)
+    rows = _dividing(mesh, [a for a in split if a != TP_AXIS], q.shape[0])
+    heads = _dividing(mesh, [a for a in split if a == TP_AXIS], q.shape[2])
+    seq, gate = P(rows, None, heads, None), P(rows, None, heads)
+    mat, vec = P(rows, heads, None, None), P(rows, heads, None)
+    return jax.shard_map(scan, in_specs=(seq, seq, seq, gate, gate, mat, vec),
+                         out_specs=(seq, (mat, vec)), axis_names=set(split),
+                         check_vma=False)(q, k, v, lf, li, C0, n0)
 
 
 def _split_axes():

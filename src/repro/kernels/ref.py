@@ -16,6 +16,7 @@ __all__ = [
     "scaled_add_ref",
     "flash_attention_ref",
     "mamba_scan_ref",
+    "mlstm_scan_ref",
 ]
 
 
@@ -130,3 +131,51 @@ def mamba_scan_ref(dt, x, Bm, Cm, A, h0, *, chunk: int):
 
     h_last, y_chunks = lax.scan(step, h0, (rs(dt), rs(x), rs(Bm), rs(Cm)))
     return jnp.moveaxis(y_chunks, 0, 1).reshape(Bt, T, di), h_last
+
+
+def mlstm_scan_ref(q, k, v, lf, li, C0, n0, *, chunk: int):
+    """The chunkwise mLSTM recurrence in plain jnp: a ``lax.scan`` over
+    chunks of ``chunk`` steps carries the matrix memory C (B, H, hd, hd)
+    and the normaliser n (B, H, hd) in f32, and each chunk is computed in
+    parallel with einsums. Any T that ``chunk`` divides. Same arguments as
+    :func:`repro.kernels.ops.mlstm_scan`; returns (h (B, T, H, hd) f32,
+    (C, n)). The tests' oracle."""
+    B, T, H, hd = q.shape
+    L = chunk
+    nc = T // L
+
+    def rs(a):  # (B,T,H,...) -> (nc,B,H,L,...)
+        a = jnp.moveaxis(a.reshape(B, nc, L, *a.shape[2:]), 1, 0)
+        return jnp.moveaxis(a, 3, 2) if a.ndim >= 5 else jnp.swapaxes(a, 2, 3)
+
+    idx = jnp.arange(L)
+    causal = idx[:, None] >= idx[None, :]
+
+    def step(carry, xs):
+        C, n = carry
+        qq, kk, vv, lff, lii = xs           # (B,H,L,hd), (B,H,L)
+        qf, kf, vf = (a.astype(jnp.float32) for a in (qq, kk, vv))
+        F = jnp.cumsum(lff, axis=-1)        # (B,H,L) inclusive decay sums
+        # intra-chunk: scores_ts = (q_t.k_s) exp(F_t - F_s + li_s), s <= t
+        dec = F[..., :, None] - F[..., None, :] + lii[..., None, :]
+        dec = jnp.where(causal, dec, -jnp.inf)
+        scores = jnp.einsum("bhtd,bhsd->bhts", qf, kf) * jnp.exp(dec)
+        num = jnp.einsum("bhts,bhsd->bhtd", scores, vf)
+        # inter-chunk: exp(F_t) * (C q_t, n q_t)
+        ef = jnp.exp(F)[..., None]
+        num = num + jnp.einsum("bhtd,bhde->bhte", qf * ef, C)
+        nq = jnp.einsum("bhtd,bhd->bht", qf * ef, n)
+        # intra normalizer: sum_s exp(F_t - F_s + li_s) (k_s . q_t)
+        nq = nq + jnp.einsum("bhts,bhsd,bhtd->bht", jnp.exp(dec), kf, qf)
+        h = num / (jnp.abs(nq)[..., None] + 1.0)
+        # carry updates
+        eL = jnp.exp(F[..., -1])[..., None]                 # (B,H,1)
+        w_s = jnp.exp(F[..., -1:] - F + lii)                # (B,H,L)
+        C_new = C * eL[..., None] + jnp.einsum("bhs,bhsd,bhse->bhde", w_s, kf, vf)
+        n_new = n * eL + jnp.einsum("bhs,bhsd->bhd", w_s, kf)
+        return (C_new, n_new), h
+
+    (C_f, n_f), hs = lax.scan(step, (C0, n0), (rs(q), rs(k), rs(v), rs(lf), rs(li)))
+    # hs: (nc, B, H, L, hd) -> (B, T, H, hd)
+    h = jnp.moveaxis(jnp.moveaxis(hs, 0, 1), 2, 3)
+    return h.reshape(B, T, H, hd), (C_f, n_f)

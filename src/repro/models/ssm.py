@@ -1,15 +1,19 @@
 """Recurrent / state-space mixers: mLSTM & sLSTM (xLSTM) and Mamba (S6).
 
 TPU adaptation notes (DESIGN.md Sec. 2): the GPU reference implementations
-use fused CUDA scans. Here mLSTM processes the sequence *chunkwise*: an
-outer ``lax.scan`` carries the matrix memory across chunks while each chunk
-is computed in parallel with matmuls, which keeps the MXU busy. Mamba's
-diagonal recurrence has no matmul to give the MXU; it runs as one Pallas
-kernel pair (``kernels/mamba_scan.py``, forward and backward under a custom
-VJP) that walks time in order with the state held in VMEM, so only dt, x,
-B, C, y and the state at each chunk boundary touch HBM. Every shape takes
-it: the kernel wrapper pads di to whole lane tiles and odd prompt lengths
-to whole chunks with steps that leave the state as it was.
+use fused CUDA scans. Here the mLSTM and Mamba recurrences run as Pallas
+kernel pairs (forward and backward under a custom VJP) that walk time in
+order with the state held in VMEM; the sLSTM is a ``lax.scan`` over time.
+mLSTM (``kernels/mlstm_scan.py``) processes the
+sequence *chunkwise*: each chunk is computed with matmuls on the MXU while
+its (hd x hd) matrix memory and normaliser stay in VMEM across the chunks,
+so only q, k, v, the gates, h, the final state and (for the backward) the
+state entering each chunk touch HBM. Mamba's diagonal recurrence has no
+matmul to give the MXU; its kernel pair (``kernels/mamba_scan.py``) steps
+through time, so only dt, x, B, C, y and the state at each chunk boundary
+touch HBM. Every shape takes them: the kernel wrappers pad the channels to
+whole lane tiles and odd prompt lengths to whole chunks with steps that
+leave the state as it was.
 
 Simplification recorded in DESIGN.md: xLSTM's stabilized exponential gating
 is replaced by log-sigmoid gating (decay factors <= 1, unconditionally
@@ -41,20 +45,6 @@ __all__ = [
     "mamba_seq",
     "mamba_step",
 ]
-
-
-# ---------------------------------------------------------------------------
-# time chunks
-# ---------------------------------------------------------------------------
-
-
-def _pick_chunk(T: int, chunk: int) -> int:
-    """Largest divisor of T that is <= chunk (production Ts are powers of
-    two, so this returns `chunk`; odd smoke lengths degrade gracefully)."""
-    L = min(chunk, T)
-    while T % L:
-        L -= 1
-    return L
 
 
 # ---------------------------------------------------------------------------
@@ -99,57 +89,14 @@ def mlstm_seq(p, x, cfg, state=None):
     H = cfg.num_heads
     di = cfg.ssm_expand * d
     hd = di // H
-    L = _pick_chunk(T, cfg.ssm_chunk)
-    nc = T // L
     q, k, v, g, lf, li = _mlstm_qkvg(p, x, cfg)
-
-    def rs(a):  # (B,T,H,...) -> (nc, B, H, L, ...)
-        a = a.reshape(B, nc, L, *a.shape[2:])
-        a = jnp.moveaxis(a, 1, 0)          # (nc, B, L, ...)
-        return jnp.moveaxis(a, 3, 2) if a.ndim >= 4 else a  # heads before L
-
-    qc, kc, vc = rs(q), rs(k), rs(v)       # (nc,B,H,L,hd)? check below
-    lfc = jnp.moveaxis(lf.reshape(B, nc, L, H), 1, 0).transpose(0, 1, 3, 2)  # (nc,B,H,L)
-    lic = jnp.moveaxis(li.reshape(B, nc, L, H), 1, 0).transpose(0, 1, 3, 2)
-
     if state is None:
         C0 = jnp.zeros((B, H, hd, hd), jnp.float32)
         n0 = jnp.zeros((B, H, hd), jnp.float32)
     else:
         C0, n0 = state
-
-    idx = jnp.arange(L)
-    causal = idx[:, None] >= idx[None, :]
-
-    def step(carry, xs):
-        C, n = carry
-        qq, kk, vv, lff, lii = xs           # (B,H,L,hd), (B,H,L)
-        qf, kf, vf = (a.astype(jnp.float32) for a in (qq, kk, vv))
-        F = jnp.cumsum(lff, axis=-1)        # (B,H,L) inclusive decay sums
-        # intra-chunk: scores_ts = (q_t.k_s) exp(F_t - F_s + li_s), s <= t
-        dec = F[..., :, None] - F[..., None, :] + lii[..., None, :]
-        dec = jnp.where(causal, dec, -jnp.inf)
-        scores = jnp.einsum("bhtd,bhsd->bhts", qf, kf) * jnp.exp(dec)
-        num = jnp.einsum("bhts,bhsd->bhtd", scores, vf)
-        # inter-chunk: exp(F_t) * (C q_t, n q_t)
-        ef = jnp.exp(F)[..., None]
-        num = num + jnp.einsum("bhtd,bhde->bhte", qf * ef, C)
-        nq = jnp.einsum("bhtd,bhd->bht", qf * ef, n)
-        # intra normalizer: sum_s exp(F_t - F_s + li_s) (k_s . q_t)
-        nq = nq + jnp.einsum("bhts,bhsd,bhtd->bht", jnp.exp(dec), kf, qf)
-        h = num / (jnp.abs(nq)[..., None] + 1.0)
-        # carry updates
-        eL = jnp.exp(F[..., -1])[..., None]                 # (B,H,1)
-        w_s = jnp.exp(F[..., -1:] - F + lii)                # (B,H,L)
-        C_new = C * eL[..., None] + jnp.einsum("bhs,bhsd,bhse->bhde", w_s, kf, vf)
-        n_new = n * eL + jnp.einsum("bhs,bhsd->bhd", w_s, kf)
-        return (C_new, n_new), h
-
-    (C_f, n_f), hs = lax.scan(step, (C0, n0), (qc, kc, vc, lfc, lic))
-    # hs: (nc, B, H, L, hd) -> (B, T, di)
-    h = jnp.moveaxis(hs, 0, 1)              # (B, nc, H, L, hd)
-    h = jnp.moveaxis(h, 2, 3).reshape(B, T, di).astype(x.dtype)
-    y = down_proj(g * h, p["wo"])
+    h, (C_f, n_f) = ops.mlstm_scan(q, k, v, lf, li, C0, n0, chunk=cfg.ssm_chunk)
+    y = down_proj(g * h.reshape(B, T, di).astype(x.dtype), p["wo"])
     return y, (C_f, n_f)
 
 

@@ -257,37 +257,190 @@ print("PASS")
 """, devices=4)
 
 
-@pytest.mark.parametrize("arch,layers", [("hymba-1.5b", 8), ("xlstm-350m", 0)])
-def test_mamba_scan_in_grad_step(arch, layers):
-    """The grad step of the benchmark's configuration at published widths
-    (traced on abstract shapes, nothing computed): each of hymba's 8 hybrid
-    layers runs the forward kernel in the forward pass and again in the
-    recompute, and the backward kernel once; xLSTM runs none."""
-    import collections
-    import dataclasses
+# ---------------------------------------------------------------------------
+# mLSTM chunkwise recurrence
+
+
+def _mlstm_args(B, T, H, hd, dtype=jnp.float32, state=True):
+    """q, k, v, lf, li, C0, n0 as the model makes them: q and k scaled by
+    hd^-0.5, log-sigmoid gates with the forget gates mostly open."""
+    import jax
+
+    rng = np.random.RandomState(0)
+    f = lambda *s: rng.randn(*s).astype(np.float32)
+    qkv = [jnp.asarray(f(B, T, H, hd) * s, dtype) for s in (hd ** -0.5, hd ** -0.5, 1.0)]
+    gates = [jax.nn.log_sigmoid(jnp.asarray(f(B, T, H) + b)) for b in (2.0, 0.0)]
+    scale = 0.1 if state else 0.0
+    C0, n0 = (jnp.asarray(f(*s) * scale) for s in ((B, H, hd, hd), (B, H, hd)))
+    return (*qkv, *gates, C0, n0)
+
+
+def _mlstm_sequential(q, k, v, lf, li, C0, n0):
+    """mlstm_step's recurrence one token at a time, in f32: the plainest form."""
+    import jax
+
+    def step(carry, xs):
+        C, n = carry
+        qt, kt, vt, f, i = xs
+        f, i = jnp.exp(f)[..., None], jnp.exp(i)[..., None]
+        C = C * f[..., None] + i[..., None] * kt[..., :, None] * vt[..., None, :]
+        n = n * f + i * kt
+        num = jnp.einsum("bhd,bhde->bhe", qt, C)
+        nq = jnp.einsum("bhd,bhd->bh", qt, n)
+        return (C, n), num / (jnp.abs(nq)[..., None] + 1.0)
+
+    xs = tuple(jnp.moveaxis(a.astype(jnp.float32), 1, 0) for a in (q, k, v, lf, li))
+    (C, n), hs = jax.lax.scan(step, (C0, n0), xs)
+    return jnp.moveaxis(hs, 0, 1), (C, n)
+
+
+def _mlstm_cts(B, T, H, hd):
+    """Cotangents of h and of the final (C, n)."""
+    rng = np.random.RandomState(1)
+    dh, dC, dn = (jnp.asarray(rng.randn(*s), jnp.float32)
+                  for s in ((B, T, H, hd), (B, H, hd, hd), (B, H, hd)))
+    return dh, (dC, dn)
+
+
+@pytest.mark.parametrize("B,T,H,hd,chunk,dtype,state", [
+    (1, 256, 1, 128, 128, "float32", True),   # two whole chunks, a lane-wide head
+    (2, 300, 2, 64, 128, "float32", True),    # an odd length: 3 chunks, the last padded
+    (2, 37, 2, 32, 16, "float32", False),     # a short prompt: one chunk of 40 steps
+    (1, 256, 2, 128, 128, "bfloat16", True),  # the model's dtype: bf16 operands on the MXU
+])
+def test_mlstm_scan(B, T, H, hd, chunk, dtype, state):
+    """h, the final (C, n) and the gradients with respect to q, k, v, lf,
+    li, C0 and n0 of ``ops.mlstm_scan`` against the jnp chunked scan and a
+    token-by-token loop. Every shape takes the kernels: padded steps and
+    head columns are sliced off. With f32 inputs every dot is f32; with bf16
+    ones the kernel rounds dot operands to bf16, as XLA's DEFAULT precision
+    does to the model's f32 einsums on a TPU."""
+    import functools
 
     import jax
-    from repro.configs import get_config
+
+    from repro.kernels.mamba_scan import time_blocks
+
+    args = _mlstm_args(B, T, H, hd, jnp.dtype(dtype), state)
+    cts = _mlstm_cts(B, T, H, hd)
+    fn = lambda *a: ops.mlstm_scan(*a, chunk=chunk)
+    eqns = pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert [(eq.params["name"], len(eq.outvars)) for eq in eqns] == [("mlstm_scan_fwd", 3)]
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(f, args, cts):
+        (h, (C, n)), vjp = jax.vjp(f, *args)
+        return (h.astype(jnp.float32), C, n) + vjp((cts[0].astype(h.dtype), cts[1]))
+
+    block = time_blocks(T, chunk)[0]
+    got = run(fn, args, cts)
+    chunked = run(functools.partial(ref.mlstm_scan_ref, chunk=block if T % block == 0 else T),
+                  args, cts)
+    sequential = run(_mlstm_sequential, args, cts)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    names = ("h", "C", "n", "dq", "dk", "dv", "d_lf", "d_li", "dC0", "dn0")
+    for name, g, c, s in zip(names, got, chunked, sequential):
+        assert g.shape == s.shape, name
+        for want in (c, s):
+            scale = float(jnp.abs(want).max())
+            np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(want, np.float32),
+                                       rtol=tol, atol=tol * scale, err_msg=name)
+
+
+def test_mlstm_scan_splits_over_the_mesh(dist):
+    """Traced under a multi-device mesh (``dist.on_mesh``), the scan runs
+    as a shard_map: rows over the data axis and heads over the model axis
+    where they divide, replicated where they do not. Values and gradients
+    match the token-by-token loop."""
+    dist("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+sys.path.insert(0, "tests")
+from test_kernels import _mlstm_args, _mlstm_cts, _mlstm_sequential
+from repro.dist import on_mesh
+from repro.kernels import ops
+
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+def with_grads(f):
+    def run(args, cts):
+        (h, (C, n)), vjp = jax.vjp(f, *args)
+        return (h, C, n) + vjp(cts)
+    return run
+
+for B, H in ((2, 2), (1, 3)):
+    args, cts = _mlstm_args(B, 24, H, 32), _mlstm_cts(B, 24, H, 32)
+    want = jax.jit(with_grads(_mlstm_sequential))(args, cts)
+    args, cts = jax.device_put((args, cts), NamedSharding(mesh, P()))
+    split = jax.jit(on_mesh(with_grads(lambda *a: ops.mlstm_scan(*a, chunk=16)), mesh))
+    assert "shard_map" in str(split.trace(args, cts).jaxpr), (B, H)
+    for g, w in zip(split(args, cts), want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-5,
+                                   atol=2e-5 * float(jnp.abs(w).max()))
+print("PASS")
+""", devices=4)
+
+
+def _launches_in_grad_step(cfg):
+    """The Pallas kernels of ``cfg``'s remat grad step (traced on abstract
+    shapes at 2 x 2048, nothing computed) by name and number of outputs,
+    with how often each runs in a step. The layer stack is a scan: a kernel
+    in its body runs once per layer; none runs outside it."""
+    import collections
+
+    import jax
     from repro.models import Model
 
-    cfg = get_config(arch)
-    if layers:
-        cfg = dataclasses.replace(cfg, num_layers=layers)
     m = Model(cfg)
     params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
     jx = jax.make_jaxpr(jax.grad(lambda p, b: m.loss(p, b, remat=True)[0]))(
         params, {"tokens": tok, "labels": tok})
-    # the layer stack is a scan: a kernel in its body runs once per layer
     counts, in_scans = collections.Counter(), 0
     for eq in jx.jaxpr.eqns:
         if eq.primitive.name == "scan":
             eqns = pallas_eqns(eq.params["jaxpr"].jaxpr)
             in_scans += len(eqns)
-            counts.update({k.params["name"]: eq.params["length"] for k in eqns})
+            for k in eqns:
+                counts[k.params["name"], len(k.outvars)] += eq.params["length"]
     assert len(pallas_eqns(jx.jaxpr)) == in_scans
+    return counts
+
+
+@pytest.mark.parametrize("arch,layers", [("hymba-1.5b", 8), ("xlstm-350m", 0)])
+def test_mamba_scan_in_grad_step(arch, layers):
+    """The grad step of the benchmark's configuration at published widths:
+    each of hymba's 8 hybrid layers runs the forward kernel in the forward
+    pass and again in the recompute, and the backward kernel once; xLSTM
+    runs no Mamba kernel."""
+    import dataclasses
+
+    from repro.configs import get_config
+
+    cfg = get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    counts = {name: n for (name, _), n in _launches_in_grad_step(cfg).items()
+              if name.startswith("mamba_scan")}
     want = {"mamba_scan_fwd": 2 * layers, "mamba_scan_bwd": layers} if layers else {}
-    assert dict(counts) == want
+    assert counts == want
+
+
+@pytest.mark.parametrize("arch,mlstm_layers", [
+    ("xlstm-350m-smoke", 14), ("xlstm-350m", 21), ("hymba-1.5b-smoke", 0)])
+def test_mlstm_scan_in_grad_step(arch, mlstm_layers):
+    """Each mLSTM layer of the remat grad step runs three kernels: the
+    forward without chunk states in the forward pass (the custom VJP's remat
+    optimisation), the forward that writes them in the recompute inside the
+    backward pass, and the backward kernel. Other families run none."""
+    from repro.configs import get_config
+
+    counts = {key: n for key, n in _launches_in_grad_step(get_config(arch)).items()
+              if key[0].startswith("mlstm_scan")}
+    n = mlstm_layers
+    want = {("mlstm_scan_fwd", 3): n, ("mlstm_scan_fwd", 5): n, ("mlstm_scan_bwd", 6): n}
+    assert counts == (want if n else {})
 
 
 # ---------------------------------------------------------------------------
@@ -329,6 +482,7 @@ def test_cpu_traces_never_embed_compiled_pallas():
         (lambda: ops.flash_attention(q, kv, kv, causal=True, bq=32, bk=32),
          "flash_attention"),
         (lambda: ops.mamba_scan(*_mamba_args(1, 16, 128, 4), chunk=8), "mamba_scan"),
+        (lambda: ops.mlstm_scan(*_mlstm_args(1, 16, 1, 32), chunk=8), "mlstm_scan"),
     ]
     found = 0
     for fn, name in cases:
@@ -386,6 +540,8 @@ def test_raw_kernel_defaults_follow_the_backend(monkeypatch):
             q, kv, kv, bq=32, bk=32),
         "mamba_scan": lambda: mod("mamba_scan").mamba_scan(
             *_mamba_args(1, 24, 128, 4), chunk=8),
+        "mlstm_scan": lambda: mod("mlstm_scan").mlstm_scan(
+            *_mlstm_args(1, 24, 1, 32), chunk=8),
     }
     for name, fn in cases.items():
         eqns = pallas_eqns(jax.make_jaxpr(fn)().jaxpr)

@@ -43,19 +43,20 @@ def test_train_step_scopes(arch, mixers):
     for c in ("forward", "recompute", "backward"):
         assert {scopes.mixer(p) for p in paths if scopes.classify(p) == c} >= mixers, c
     assert any("head" in scopes.components(p) for p in paths)
-    if "mamba" in mixers:
-        # Mamba's scan kernels under remat: the forward kernel in the forward
-        # pass and the recompute, the backward kernel in the backward pass,
-        # each under mixer/mamba (here the interpreted kernels' ops; on a TPU
-        # the kernel's one custom call). A reduce's combiner runs inside the
-        # reduce, and one in an interpreted branch keeps a name relative to
-        # the branch, so combiners are left out.
+    # the mixer's scan kernels under remat (Mamba's, and the mLSTM's): the
+    # forward kernel in the forward pass and the recompute, the backward
+    # kernel in the backward pass, each under its mixer (here the
+    # interpreted kernels' ops; on a TPU the kernel's one custom call). A
+    # reduce's combiner runs inside the reduce, and one in an interpreted
+    # branch keeps a name relative to the branch, so combiners are left out.
+    kinds = {"mamba": "mamba_scan", "mlstm": "mlstm_scan"}
+    for mixer in mixers & set(kinds):
+        fwd, bwd = kinds[mixer] + "_fwd", kinds[mixer] + "_bwd"
         kernels = {(k, scopes.classify(n), scopes.mixer(n))
                    for n in scopes.op_names(_without_combiners(text)).values()
-                   for k in ("mamba_scan_fwd", "mamba_scan_bwd") if k in n}
-        assert kernels == {("mamba_scan_fwd", "forward", "mamba"),
-                           ("mamba_scan_fwd", "recompute", "mamba"),
-                           ("mamba_scan_bwd", "backward", "mamba")}, kernels
+                   for k in (fwd, bwd) if k in n}
+        assert kernels == {(fwd, "forward", mixer), (fwd, "recompute", mixer),
+                           (bwd, "backward", mixer)}, kernels
 
 
 def _without_combiners(hlo_text: str) -> str:

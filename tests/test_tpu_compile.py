@@ -6,7 +6,8 @@ is described but not attached, and refuses what the chip would refuse
 either. The widths are the ones the system uses: a 64 MiB f32 gradient
 bucket in 16 chunks, the serving engine's 4 MiB bf16 weight bucket, and
 hymba-1.5b's Mamba scan over a batch of 2 x 2048, alone and inside the
-trainer's step and the engine's prefill on four chips; Moonlight-16B-A3B's
+trainer's step and the engine's prefill on four chips; xlstm-350m's mLSTM
+recurrence at 8 x 2048 and 2 x 2048; Moonlight-16B-A3B's
 latent attention through the splash kernel, and its training step cut to
 two layers on one chip and on four.
 
@@ -107,6 +108,30 @@ def test_mamba_scan_compiles(one_chip, Bt, T, di):
         return jnp.sum(y) + jnp.sum(h_last)
 
     assert "mamba_scan_bwd" in _compile(jax.grad(loss, argnums=range(6)), *args).as_text()
+
+
+@pytest.mark.parametrize("B,T,H,hd", [
+    (8, 2048, 4, 512),  # xlstm-350m's training step on one chip: 16 chunks of 128
+    (2, 2048, 4, 512),  # its share of a chip in the four-chip data-parallel step
+    (1, 301, 4, 32),    # an odd prompt at a smoke head dim: padded steps and lanes
+])
+def test_mlstm_scan_compiles(one_chip, B, T, H, hd):
+    """Forward (with and without chunk states) and backward kernels of the
+    mLSTM recurrence, bf16 q, k, v as the model projects them."""
+    from repro.kernels.mlstm_scan import mlstm_scan
+
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    args = (bf16(B, T, H, hd),) * 3 + (f32(B, T, H),) * 2 + (f32(B, H, hd, hd), f32(B, H, hd))
+    scan = lambda *a: mlstm_scan(*a, chunk=128, interpret=False)
+    assert "mlstm_scan_fwd" in _compile(scan, *args).as_text()
+
+    def loss(*a):
+        h, (C, n) = scan(*a)
+        return jnp.sum(h.astype(jnp.float32)) + jnp.sum(C) + jnp.sum(n)
+
+    text = _compile(jax.grad(loss, argnums=range(7)), *args).as_text()
+    assert "mlstm_scan_fwd" in text and "mlstm_scan_bwd" in text
 
 
 def test_mla_splash_attention_compiles(one_chip):
